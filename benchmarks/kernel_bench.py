@@ -48,8 +48,8 @@ def bench_kernels():
     B, H, Hkv, D, page, maxp = 4, 32, 8, 128, 16, 8
     P = B * maxp + 4
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), jnp.bfloat16)
     tab = jnp.asarray(rng.integers(0, P, (B, maxp)), jnp.int32)
     ctx = jnp.full((B,), maxp * page, jnp.int32)
     us = _time(lambda *a: paged_attention(*a, interpret=interp),
@@ -151,7 +151,7 @@ def bench_step(decode_steps: int = 16):
     payload["compile_counts"] = model.paged_compile_counts()
     payload["interference"] = bench_interference()
     payload["overlap"] = bench_overlap()
-    payload["mesh"] = _bench_mesh_subprocess()
+    payload["mesh"] = bench_mesh()
     save("BENCH_step", payload)
     return payload
 
@@ -165,10 +165,10 @@ def bench_mesh(tp: int = 2, decode_steps: int = 16):
     pool), steady-state decode latency for both, and the mesh-keyed
     compile census.  Each mesh placement is warmed by an identical pass
     first, so the measured pass must stay at ZERO compiles (the CI gate).
-    Requires ``tp`` visible devices — on CPU run under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (``bench_step``
-    spawns this mode in a subprocess with that env so the single-device
-    numbers in the same artifact stay pristine)."""
+    Requires ``tp`` visible devices and runs in this process (a child
+    process could not reach a chip the parent already holds) — on CPU run
+    under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; with too
+    few devices `make_serving_mesh` raises and the bench fails."""
     from repro.configs import get_config
     from repro.core.advisory import InferenceRequest
     from repro.core.node_manager import NodeManager
@@ -230,34 +230,6 @@ def bench_mesh(tp: int = 2, decode_steps: int = 16):
          f"measured_compiles={out['meshed']['measured_compiles']}")
     save("BENCH_mesh", out)
     return out
-
-
-def _bench_mesh_subprocess(tp: int = 2):
-    """Run ``--mesh-only`` in a child whose XLA_FLAGS append the forced
-    host-device count (this process already initialized jax with however
-    many devices it has, so it cannot grow a mesh in place).  Returns the
-    child's BENCH_mesh payload, or an {error} stub off-CI (never fails the
-    single-device artifact)."""
-    import json
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    from benchmarks.common import RESULTS
-
-    env = dict(os.environ)
-    flag = "--xla_force_host_platform_device_count"
-    if flag not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {flag}=4".strip()
-    root = Path(__file__).resolve().parents[1]
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.kernel_bench", "--mesh-only",
-         "--tp", str(tp)],
-        cwd=root, env=env, capture_output=True, text=True, timeout=1200)
-    if r.returncode != 0:
-        return dict(error=(r.stderr or "")[-2000:])
-    return json.loads((RESULTS / "BENCH_mesh.json").read_text())
 
 
 def bench_overlap(ctx_len: int = 1536, lead_steps: int = 4,
@@ -944,8 +916,8 @@ def bench_quant(n_sessions: int = 10, kernel_mode: str = None):
     rng = np.random.default_rng(0)
     Hkv, H, D, P, page, B, Sq, maxp = 2, 4, 16, 8, 8, 2, 8, 3
     q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), jnp.float32)
     tab = jnp.asarray(rng.integers(0, P, (B, maxp)), jnp.int32)
     qo = jnp.asarray([5, 16], jnp.int32)
     ctx = qo + Sq
@@ -1140,6 +1112,8 @@ if __name__ == "__main__":
     ap.add_argument("--token-budget", type=int, default=4)
     ap.add_argument("--sessions", type=int, default=1000)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.interference_only:
         import json
         print(json.dumps(bench_interference(args.prompt_len,

@@ -31,11 +31,9 @@ import time
 
 def _mesh_summary(mesh):
     """Tensor-parallel serving section of the scorecard (None when the
-    step artifact predates the mesh mode or its subprocess errored)."""
+    step artifact predates the mesh mode)."""
     if not mesh:
         return None
-    if "error" in mesh:
-        return dict(error=mesh["error"])
     return dict(
         tp=mesh.get("tp"),
         pool_spec=mesh.get("meshed", {}).get("pool_spec"),
@@ -199,6 +197,8 @@ def main() -> None:
         return
 
     from benchmarks import fig_serving, fig_tokens
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.roofline_table import emit_roofline
     from benchmarks.kernel_bench import (bench_hetero, bench_kernels,
                                          bench_quant, bench_recurrent,
@@ -237,10 +237,7 @@ def main() -> None:
         if args.only and args.only != name:
             continue
         print(f"# --- {name} ---", flush=True)
-        try:
-            fn()
-        except Exception as e:  # keep the harness running
-            print(f"{name},0,ERROR:{type(e).__name__}:{e}", flush=True)
+        fn()                  # a failed section fails the run
     print(f"# total {time.time()-t0:.0f}s")
 
 

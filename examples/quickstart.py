@@ -27,6 +27,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.advisory import InferenceRequest
 from repro.core.node_manager import NodeManager
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import get_model
 from repro.serving.backend import RealBackend
 from repro.serving.cost_model import CostModel, HardwareSpec
@@ -36,6 +37,7 @@ GEN = 8
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("llama3-8b").reduced(dtype="float32")
     model = get_model(cfg)
     params = model.init(jax.random.key(0))

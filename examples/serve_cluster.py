@@ -34,6 +34,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import get_model
 from repro.serving.cost_model import HardwareSpec
 from repro.serving.scenario import (MultiTurnRealTrace, dense_reference,
@@ -44,6 +45,7 @@ N_NODES, N_SESSIONS, N_TURNS, GEN = 3, 2, 4, 8
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("llama3-8b").reduced(dtype="float32")
     model = get_model(cfg)
     params = model.init(jax.random.key(0))

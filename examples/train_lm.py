@@ -7,6 +7,7 @@ import argparse
 import dataclasses
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import get_model
 from repro.training.data import DataConfig
 from repro.training.train_loop import TrainConfig, train
@@ -18,6 +19,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=512)
     ap.add_argument("--layers", type=int, default=8)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = dataclasses.replace(
         get_config("llama3-8b"), name="llama-100m",

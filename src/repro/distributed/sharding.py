@@ -184,17 +184,18 @@ class ShardingPlan:
         return P(*parts)
 
     def pool_spec(self, shape: Tuple[int, ...]) -> P:
-        """Sharding of the serving backend's STACKED physical page pool
-        ``(L, P+1, page, Hkv, D)``.  Same tensor-parallel ladder as
-        `cache_spec`'s kv-like branch: kv-heads -> ``model`` when divisible,
-        else the split-K sequence fallback on the page-slot dim (GSPMD then
-        derives a flash-decoding-style softmax combine, the GQA plan whose
-        kv_heads < tp), else the head-feature dim, else replicate.  The
-        layer and page-index dims are NEVER sharded: a block-table entry
-        must address the same page on every shard (tier transfers and CoW
-        forks are per-page), and the trash page (index P) must exist on
-        every shard."""
-        Ldim, Pdim, Sdim, Hdim, Ddim = range(5)
+        """Sharding of the serving backend's STACKED head-major page pool
+        ``(L, P+1, Hkv, page, D)``.  Same tensor-parallel ladder as
+        `cache_spec`'s kv-like branch: kv-heads -> ``model`` when divisible
+        (the only split the per-shard Pallas kernel serves), else the
+        split-K sequence fallback on the page-slot dim (GSPMD then derives
+        a flash-decoding-style softmax combine over the jnp oracle, the GQA
+        plan whose kv_heads < tp), else the head-feature dim, else
+        replicate.  The layer and page-index dims are NEVER sharded: a
+        block-table entry must address the same page on every shard (tier
+        transfers and CoW forks are per-page), and the trash page (index
+        P) must exist on every shard."""
+        Ldim, Pdim, Hdim, Sdim, Ddim = range(5)
         parts: list = [None] * len(shape)
         if self._div(shape[Hdim], "model"):
             parts[Hdim] = "model"

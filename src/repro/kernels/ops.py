@@ -1,6 +1,8 @@
 """Public jit'd kernel entrypoints with automatic backend dispatch:
 Pallas on TPU (interpret=False), interpret-mode on CPU for validation,
-pure-jnp oracle as the universal fallback.
+pure-jnp oracle as the universal fallback.  On a device mesh the serving
+kernel runs per shard inside `jax.shard_map` over the ``model`` axis: a
+Mosaic kernel cannot be partitioned by the compiler.
 
 Tile sizes default to "largest divisor of the (bucketed) sequence length
 <= 128" so the serving path never has to thread static block shapes through
@@ -11,6 +13,7 @@ compiled prefill kernel serve every turn/context length in a bucket."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_prefill import flash_prefill as _flash
@@ -24,19 +27,33 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def serving_kernel_mode(requested: str = "auto", *, meshed: bool = False
-                        ) -> str:
-    """Resolve the serving backend's attention-kernel mode.
+def serving_kernel_mode(requested: str = "auto", *, meshed: bool = False,
+                        heads_sharded: bool = True) -> str:
+    """Resolve the serving backend's attention-kernel mode to a concrete
+    ``pallas`` | ``interpret`` | ``ref``.
 
-    On TPU ``auto`` stays ``auto`` (real Pallas, meshed or not — GSPMD
-    partitions the kernel's batch grid).  On CPU a MESHED backend resolves
-    ``auto`` to ``ref``: the jnp oracle is plain HLO that GSPMD partitions
-    along the sharded kv-head (or split-K page-slot) axis, whereas
-    interpret-mode Pallas walks the page grid in software per device and
-    would serialize the mesh.  An explicit mode is always honored."""
-    if requested != "auto" or _on_tpu():
-        return requested
-    return "ref" if meshed else requested
+    ``auto`` is compiled Pallas on TPU, meshed or not: on a mesh the
+    kernel runs per shard inside `jax.shard_map` over kv-heads (the
+    compiler refuses to partition a Mosaic kernel).  On CPU ``auto`` is
+    interpret-mode Pallas, except on a mesh, where it is ``ref``: the jnp
+    oracle is plain HLO that GSPMD partitions along the sharded kv-head
+    (or split-K page-slot) axis, whereas interpret-mode Pallas walks the
+    page grid in software per device and would serialize the mesh.
+
+    A Pallas mode on a mesh needs pools sharded by kv-heads
+    (``heads_sharded``).  The split-K page-slot fallback (GQA with
+    n_kv_heads % tp != 0) has no per-shard kernel, so it raises instead
+    of quietly serving from the oracle."""
+    mode = requested
+    if mode == "auto":
+        mode = "pallas" if _on_tpu() else ("ref" if meshed else "interpret")
+    if meshed and mode != "ref" and not heads_sharded:
+        raise ValueError(
+            f"kernel mode {mode!r} on a mesh needs the KV pools sharded by "
+            f"kv-heads; this pool is split on page slots or replicated "
+            f"(n_kv_heads not divisible by tp) — choose tp dividing "
+            f"n_kv_heads")
+    return mode
 
 
 def _auto_tile(n: int, cap: int = 128) -> int:
@@ -67,25 +84,44 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
                           ctx_lens, mode: str = "auto", bq=None,
-                          quant=None):
+                          quant=None, mesh=None):
     """Unified mixed-batch serving attention (decode = 1-token chunk).
     mode: auto | pallas | interpret | ref.  ``quant``: optional
     (kq_pages, vq_pages, k_scales, v_scales, page_quant) mixed-precision
     shadow state — pages flagged quantized dequantize inside the kernel
-    (or oracle) instead of reading the fp pool."""
+    (or oracle) instead of reading the fp pool.
+
+    ``mesh``: a mesh with a ``model`` axis over which q (heads) and the
+    pools (kv-heads, dim 1) are sharded.  A Pallas mode then runs the
+    kernel per shard under `jax.shard_map` (tables, offsets, context
+    lengths, scales and precision bits replicated); ``ref`` ignores it
+    and lets GSPMD partition the oracle."""
     if mode == "ref":
         return ref.paged_chunk_attention_ref(q, k_pages, v_pages,
                                              block_tables, q_offsets,
                                              ctx_lens, quant=quant)
     interpret = not _on_tpu() if mode == "auto" else (mode == "interpret")
     bq = _auto_tile(q.shape[1]) if bq is None else bq
-    if quant is not None:
-        kq, vq, ks, vs, pq = quant
-        return _chunk_quant(q, k_pages, v_pages, kq, vq, ks, vs, pq,
-                            block_tables, q_offsets, ctx_lens,
-                            bq=bq, interpret=interpret)
-    return _chunk(q, k_pages, v_pages, block_tables, q_offsets, ctx_lens,
-                  bq=bq, interpret=interpret)
+
+    def kernel(q, k_pages, v_pages, block_tables, q_offsets, ctx_lens,
+               quant):
+        if quant is not None:
+            kq, vq, ks, vs, pq = quant
+            return _chunk_quant(q, k_pages, v_pages, kq, vq, ks, vs, pq,
+                                block_tables, q_offsets, ctx_lens,
+                                bq=bq, interpret=interpret)
+        return _chunk(q, k_pages, v_pages, block_tables, q_offsets,
+                      ctx_lens, bq=bq, interpret=interpret)
+
+    if mesh is not None:
+        heads = P(None, None, "model", None)
+        pool = P(None, "model", None, None)
+        quant_spec = None if quant is None else (pool, pool, P(), P(), P())
+        kernel = jax.shard_map(
+            kernel, mesh=mesh, check_vma=False, out_specs=heads,
+            in_specs=(heads, pool, pool, P(), P(), P(), quant_spec))
+    return kernel(q, k_pages, v_pages, block_tables, q_offsets, ctx_lens,
+                  quant)
 
 
 def flash_prefill(q, k, v, q_offset=0, mode: str = "auto",

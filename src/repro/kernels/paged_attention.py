@@ -24,7 +24,13 @@ PagedAttention gather, reshaped for VMEM/MXU:
     MXU contraction is (G x D) @ (D x page_size).
 
 Pages are the unit SYMPHONY migrates between tiers/nodes, so serving decode
-reads KV exactly in the layout the node manager stores it.
+reads KV exactly in the layout the node manager stores it.  That layout is
+HEAD-MAJOR, ``(P, Hkv, page, D)`` per layer: one grid step's K/V block is
+``(None, None, page, D)``, whose last two dims are a whole (page, D) tile —
+Mosaic requires a block's last two dims to be (8, 128)-divisible or equal
+to the array's, which a token-major ``(P, page, Hkv, D)`` pool's (1, D)
+head slice is not.  Host payloads stay token-major ``(tokens, Hkv, D)``;
+`pages_to_tokens` is the one conversion.
 
 Dynamic-masking + DMA-elision contract (what shape-bucketed dispatch leans
 on): ctx_lens and block tables are traced data, never static shapes, so one
@@ -68,6 +74,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def pages_to_tokens(pages):
+    """Head-major pages ``(..., n, Hkv, page, D)`` -> token rows
+    ``(..., n * page, Hkv, D)``: the gather side of every tier transfer and
+    of the jnp oracles."""
+    *lead, n, hkv, page, d = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, n * page, hkv, d)
+
+
 def _dim_semantics(interpret: bool, n_parallel: int):
     """Megacore partitioning hint: batch/head/q-block grid dims are
     embarrassingly parallel, only the page walk (innermost) carries the
@@ -76,7 +90,7 @@ def _dim_semantics(interpret: bool, n_parallel: int):
     if interpret:
         return {}
     sem = ("parallel",) * n_parallel + ("arbitrary",)
-    return dict(compiler_params=pltpu.TPUCompilerParams(
+    return dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=sem))
 
 
@@ -87,7 +101,7 @@ def _kernel(ctx_ref, npages_ref, tables_ref,  # scalar prefetch (SMEM)
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_pages = pl.num_programs(2)
-    page = k_ref.shape[1]
+    page = k_ref.shape[0]
 
     @pl.when(p == 0)
     def _init():
@@ -102,8 +116,8 @@ def _kernel(ctx_ref, npages_ref, tables_ref,  # scalar prefetch (SMEM)
     @pl.when(valid > 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)                 # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s / np.sqrt(q.shape[-1])                       # (G, page)
@@ -147,7 +161,7 @@ def _chunk_body(refs, *, bq: int, G: int, quant: bool):
     qi = pl.program_id(2)
     p = pl.program_id(3)
     n_pages = pl.num_programs(3)
-    page = k_ref.shape[1]
+    page = k_ref.shape[0]
 
     @pl.when(p == 0)
     def _init():
@@ -169,18 +183,19 @@ def _chunk_body(refs, *, bq: int, G: int, quant: bool):
     @pl.when(valid > 0)
     def _compute():
         q = q_ref[0, 0].reshape(bq * G, -1).astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)                 # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         if quant:
+            # dequantize to the pool dtype, exactly as a tier payload that
+            # leaves the device re-inflates, so both paths read one value
             pid = tables_ref[b, p]
             isq = pq_ref[pid] > 0
-            k = jnp.where(isq,
-                          kq_ref[0, :, 0].astype(jnp.float32) * ks_ref[pid],
-                          k_ref[0, :, 0].astype(jnp.float32))  # (page, D)
-            v = jnp.where(isq,
-                          vq_ref[0, :, 0].astype(jnp.float32) * vs_ref[pid],
-                          v_ref[0, :, 0].astype(jnp.float32))
-        else:
-            k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-            v = v_ref[0, :, 0].astype(jnp.float32)
+            kd = (kq_ref[...].astype(jnp.float32) * ks_ref[pid]
+                  ).astype(k_ref.dtype).astype(jnp.float32)
+            vd = (vq_ref[...].astype(jnp.float32) * vs_ref[pid]
+                  ).astype(v_ref.dtype).astype(jnp.float32)
+            k = jnp.where(isq, kd, k)
+            v = jnp.where(isq, vd, v)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s / np.sqrt(q.shape[-1])                       # (bq*G, page)
@@ -231,7 +246,7 @@ def _chunk_kv_index(bq: int, page: int):
         horizon = (qo[b] + (qi + 1) * bq - 1) // page + 1
         rel = jnp.minimum(npg[b], horizon)
         p_eff = jnp.minimum(p, jnp.maximum(rel - 1, 0))
-        return (tab[b, p_eff], 0, h, 0)
+        return (tab[b, p_eff], h, 0, 0)
     return kv_index
 
 
@@ -243,12 +258,12 @@ def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
     """`paged_chunk_attention` over mixed-precision pools: pages whose
     ``page_quant`` bit is set are read from the int8 shadow pool and
     dequantized in the kernel body with their per-page fp32 scale; the
-    rest read the fp pool.  kq/vq_pages: (P, page, Hkv, D) int8;
+    rest read the fp pool.  kq/vq_pages: (P, Hkv, page, D) int8;
     k/v_scales, page_quant: (P,).  Same grid/masking/DMA-elision contract
     as the all-fp kernel (the fp and int8 tiles of a page share one
     clamped index map, so both copies are elided together)."""
     B, Sq, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     bq = min(bq, Sq)
@@ -258,7 +273,8 @@ def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
 
     grid = (B, Hkv, Sq // bq, maxp)
     kern = functools.partial(_chunk_kernel_quant, bq=bq, G=G)
-    kv_spec = pl.BlockSpec((1, page, 1, D), _chunk_kv_index(bq, page))
+    kv_spec = pl.BlockSpec((None, None, page, D),
+                           _chunk_kv_index(bq, page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -293,7 +309,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
                           interpret: bool = True):
     """Mixed-batch paged attention: every lane carries a chunk of queries.
 
-    q: (B, Sq, H, D); k/v_pages: (P, page, Hkv, D); block_tables: (B, maxp);
+    q: (B, Sq, H, D); k/v_pages: (P, Hkv, page, D); block_tables: (B, maxp);
     q_offsets/ctx_lens: (B,) int32.  Lane b's query token i sits at absolute
     position q_offsets[b] + i and attends KV positions <= it (causal) that
     are < ctx_lens[b].  Decode is the Sq = 1 special case (q_offset =
@@ -311,7 +327,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
     bandwidth proportional to its OWN pages, not the bucket width — see the
     module docstring for the table-padding invariant this leans on."""
     B, Sq, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     bq = min(bq, Sq)
@@ -321,7 +337,8 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
 
     grid = (B, Hkv, Sq // bq, maxp)
     kern = functools.partial(_chunk_kernel, bq=bq, G=G)
-    kv_spec = pl.BlockSpec((1, page, 1, D), _chunk_kv_index(bq, page))
+    kv_spec = pl.BlockSpec((None, None, page, D),
+                           _chunk_kv_index(bq, page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
@@ -350,12 +367,12 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
                     *, interpret: bool = True):
-    """q: (B,H,D); k/v_pages: (P,page,Hkv,D); block_tables: (B,maxp);
+    """q: (B,H,D); k/v_pages: (P,Hkv,page,D); block_tables: (B,maxp);
     ctx_lens: (B,). Returns (B,H,D).  Same DMA-elision contract as the
     chunk kernel: pages past ceil(ctx/page) re-map to the lane's last
     relevant page and their copies are elided."""
     B, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     q4 = q.reshape(B, Hkv, G, D)
@@ -363,10 +380,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
     def kv_index(b, h, p, ctx, npg, tab):
         p_eff = jnp.minimum(p, jnp.maximum(npg[b] - 1, 0))
-        return (tab[b, p_eff], 0, h, 0)
+        return (tab[b, p_eff], h, 0, 0)
 
     grid = (B, Hkv, maxp)
-    kv_spec = pl.BlockSpec((1, page, 1, D), kv_index)
+    kv_spec = pl.BlockSpec((None, None, page, D), kv_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,

@@ -5,12 +5,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_to_tokens
+
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens):
     """Decode attention over paged KV.
 
     q:            (B, H, D)
-    k/v_pages:    (P, page_size, Hkv, D)  — global page pool
+    k/v_pages:    (P, Hkv, page_size, D)  — global page pool, head-major
     block_tables: (B, max_pages) int32    — page ids per sequence
     ctx_lens:     (B,) int32              — valid tokens per sequence
     returns:      (B, H, D)
@@ -24,14 +26,14 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens):
     and are dropped by the mask.
     """
     B, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     S = maxp * page
 
     # gather each sequence's pages into dense (B, S, Hkv, D)
-    k = k_pages[block_tables].reshape(B, S, Hkv, D)
-    v = v_pages[block_tables].reshape(B, S, Hkv, D)
+    k = pages_to_tokens(k_pages[block_tables])
+    v = pages_to_tokens(v_pages[block_tables])
     qg = q.reshape(B, Hkv, G, D).astype(jnp.float32)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k.astype(jnp.float32))
     s = s / np.sqrt(D)
@@ -47,16 +49,17 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables,
     """Mixed-batch paged attention: each lane is a chunk of queries.
 
     q:            (B, Sq, H, D) — lane b's token i at position q_offsets[b]+i
-    k/v_pages:    (P, page_size, Hkv, D)
+    k/v_pages:    (P, Hkv, page_size, D)
     block_tables: (B, max_pages) int32
     q_offsets:    (B,) int32 — cached context before the chunk
     ctx_lens:     (B,) int32 — total valid KV incl. the chunk (0 = padded
                   lane, output zeroed to match the kernel's page skip)
     quant:        optional (kq_pages, vq_pages, k_scales, v_scales,
-                  page_quant) — int8 shadow pools (P, page, Hkv, D),
+                  page_quant) — int8 shadow pools (P, Hkv, page, D),
                   per-page fp32 scales (P,), and the per-page precision
                   bit (P,) int32; pages flagged quantized are dequantized
-                  from the shadow pool, the rest read full precision
+                  from the shadow pool to the pool dtype, the rest read
+                  full precision
     returns:      (B, Sq, H, D)
 
     Relevance contract (mirrors the kernel's clamped index maps): a KV
@@ -68,7 +71,7 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables,
     duplicated rows sit at kpos >= ctx and never survive the mask.
     """
     B, Sq, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     S = maxp * page
@@ -78,14 +81,16 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables,
     if quant is not None:
         kq_pages, vq_pages, k_scales, v_scales, page_quant = quant
         isq = (page_quant[block_tables] > 0)[..., None, None, None]
-        kd = kq_pages[block_tables].astype(jnp.float32) \
-            * k_scales[block_tables][..., None, None, None]
-        vd = vq_pages[block_tables].astype(jnp.float32) \
-            * v_scales[block_tables][..., None, None, None]
-        k = jnp.where(isq, kd, k.astype(jnp.float32))
-        v = jnp.where(isq, vd, v.astype(jnp.float32))
-    k = k.reshape(B, S, Hkv, D)
-    v = v.reshape(B, S, Hkv, D)
+        kd = (kq_pages[block_tables].astype(jnp.float32)
+              * k_scales[block_tables][..., None, None, None]
+              ).astype(k.dtype)
+        vd = (vq_pages[block_tables].astype(jnp.float32)
+              * v_scales[block_tables][..., None, None, None]
+              ).astype(v.dtype)
+        k = jnp.where(isq, kd, k)
+        v = jnp.where(isq, vd, v)
+    k = pages_to_tokens(k)
+    v = pages_to_tokens(v)
     qg = q.reshape(B, Sq, Hkv, G, D).astype(jnp.float32)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
     s = s / np.sqrt(D)

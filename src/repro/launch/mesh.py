@@ -19,9 +19,9 @@ def force_host_device_count(n: int) -> bool:
     """Append ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``,
     PRESERVING any flags the user already set.  An existing forced count
     (user-chosen device topology) is respected, not overwritten.  Must run
-    before jax initializes its backends — a no-op afterwards, which is why
-    multi-device benches re-exec themselves in a subprocess instead of
-    calling this late.  Returns whether the flag is (now) present."""
+    before jax initializes its backends — a no-op afterwards, so a
+    multi-device CPU bench sets ``XLA_FLAGS`` before it starts.  Returns
+    whether the flag is (now) present."""
     cur = os.environ.get("XLA_FLAGS", "")
     if _FORCE_FLAG in cur:
         return True
@@ -51,11 +51,8 @@ def make_serving_mesh(tp: int = 1):
             f"make_serving_mesh(tp={tp}): only {jax.device_count()} devices "
             f"visible — on CPU, force host devices via XLA_FLAGS "
             f"({_FORCE_FLAG}=N) before jax initializes")
-    try:  # axis_types landed after 0.4.37; Auto is the default either way
-        return jax.make_mesh((tp,), ("model",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        return jax.make_mesh((tp,), ("model",))
+    return jax.make_mesh((tp,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):

@@ -517,8 +517,8 @@ class Zamba2LM:
         positions = q_offsets[:, None] + jnp.arange(S)[None, :]
         q = L.apply_rope(q, positions, c.rope_theta)
         k = L.apply_rope(k, positions, c.rope_theta)
-        kp = kp.at[slot_pages, slot_offs].set(k.astype(kp.dtype))
-        vp = vp.at[slot_pages, slot_offs].set(v.astype(vp.dtype))
+        kp = kp.at[slot_pages, :, slot_offs].set(k.astype(kp.dtype))
+        vp = vp.at[slot_pages, :, slot_offs].set(v.astype(vp.dtype))
         o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets, ctx_lens,
                                       mode=kernel_mode)
         x = x + (o.reshape(B, S, -1) @ w["wo"])
@@ -531,7 +531,7 @@ class Zamba2LM:
                                 q_offsets, ctx_lens, slot_pages, slot_offs,
                                 *, kernel_mode):
         """Hybrid step: recurrent slot pools + per-application paged KV.
-        k/v pools are (na, P+1, page, Hkv, D); tables/slot_pages/slot_offs
+        k/v pools are (na, P+1, Hkv, page, D); tables/slot_pages/slot_offs
         carry a leading (na,) axis and ride the group scan."""
         c = self.cfg
         B, Sq = token_ids.shape
@@ -600,8 +600,10 @@ class Zamba2LM:
 
     @staticmethod
     def _scatter_paged_impl(k_pool, v_pool, app_ids, pages, offs, ks, vs):
-        k_pool = k_pool.at[app_ids, pages, offs].set(ks.astype(k_pool.dtype))
-        v_pool = v_pool.at[app_ids, pages, offs].set(vs.astype(v_pool.dtype))
+        k_pool = k_pool.at[app_ids, pages, :, offs].set(
+            ks.astype(k_pool.dtype))
+        v_pool = v_pool.at[app_ids, pages, :, offs].set(
+            vs.astype(v_pool.dtype))
         return k_pool, v_pool
 
     def scatter_paged(self, k_pool, v_pool, app_ids, pages, offs, ks, vs):

@@ -238,7 +238,8 @@ class DenseLM:
     # -- paged entry point (RealBackend serving path) -------------------------
     #
     # Same math as prefill()/decode_step(), but the KV lives in ONE stacked
-    # physical page pool (L, P, page, Hkv, D) addressed through block
+    # physical page pool (L, P, Hkv, page, D) — head-major, the layout the
+    # Pallas kernel's (page, D) tiles need — addressed through block
     # tables — the layout the SYMPHONY node manager migrates between tiers,
     # and the layout that lets tier transfers move all L layers in a single
     # device<->host copy.  There is ONE entry point, `step_paged`: a MIXED
@@ -263,7 +264,7 @@ class DenseLM:
 
     def _step_paged_impl(self, params, token_ids, k_pool, v_pool, tables,
                          q_offsets, ctx_lens, last_idx, slot_pages,
-                         slot_offs, quant=None, *, kernel_mode):
+                         slot_offs, quant=None, *, kernel_mode, mesh=None):
         from repro.kernels import ops
         c = self.cfg
         ids = jnp.asarray(token_ids, jnp.int32)
@@ -290,11 +291,11 @@ class DenseLM:
                 k = L.rms_norm(k, w["kn"], c.norm_eps)
             q = L.apply_rope(q, positions, c.rope_theta)
             k = L.apply_rope(k, positions, c.rope_theta)
-            kp = kp.at[sp, so].set(k.astype(kp.dtype))
-            vp = vp.at[sp, so].set(v.astype(vp.dtype))
+            kp = kp.at[sp, :, so].set(k.astype(kp.dtype))
+            vp = vp.at[sp, :, so].set(v.astype(vp.dtype))
             o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets,
                                           ctx_lens, mode=kernel_mode,
-                                          quant=qt)
+                                          quant=qt, mesh=mesh)
             x = x + o.reshape(B, Sq, -1) @ w["wo"]
             h2 = L.rms_norm(x, w["ln2"], c.norm_eps)
             x = x + L.swiglu(h2, w["w1"], w["w3"], w["w2"])
@@ -332,7 +333,7 @@ class DenseLM:
           q_len[b] = ctx_lens[b] - q_offsets[b] slots are this step's real
           tokens (a decode lane's pending token, or a chunk of prompt);
           their KV lands at absolute positions [q_offsets[b], ctx_lens[b]).
-        k_pool/v_pool: (L, P, page, Hkv, D) stacked pools.
+        k_pool/v_pool: (L, P, Hkv, page, D) stacked head-major pools.
         tables: (L, B, T) int32 block tables.  Columns beyond a lane's own
           pages must repeat the lane's LAST VALID page id (what
           ``PagedAllocator.block_table`` emits) — padded columns are fully
@@ -355,25 +356,12 @@ class DenseLM:
           (None = single device).  The scan carry's pool shardings are
           PINNED to it via out_shardings so donation still aliases input to
           output on every mesh; token ids and logits are pinned replicated
-          (both are host-fetched every step).
+          (both are host-fetched every step).  A Pallas kernel mode runs
+          the attention kernel per kv-head shard under `jax.shard_map` on
+          this sharding's mesh.
         Returns (argmax token ids (B,), logits (B, V), k_pool, v_pool).
         """
-        key = self._mesh_sig(pool_sharding)
-        jit_fn = self._step_jits.get(key)
-        if jit_fn is None:
-            # donate the pools: the backend unconditionally replaces its
-            # references with the returned pools, and aliasing input to
-            # output keeps peak memory at 1x the stacked pool per side
-            # (per shard, on a mesh)
-            kw = dict(static_argnames=("kernel_mode",),
-                      donate_argnums=(2, 3))
-            if pool_sharding is not None:
-                repl = jax.sharding.NamedSharding(
-                    pool_sharding.mesh, jax.sharding.PartitionSpec())
-                kw["out_shardings"] = (repl, repl, pool_sharding,
-                                       pool_sharding)
-            jit_fn = self._step_jits[key] = jax.jit(self._step_paged_impl,
-                                                    **kw)
+        key, jit_fn = self._step_jit(pool_sharding)
         args = (params, token_ids, k_pool, v_pool, tables,
                 q_offsets, ctx_lens, last_idx, slot_pages, slot_offs,
                 quant)
@@ -381,10 +369,38 @@ class DenseLM:
             (key,) + self._shape_sig(args, kernel_mode))
         return jit_fn(*args, kernel_mode=kernel_mode)
 
+    def _step_jit(self, pool_sharding):
+        key = self._mesh_sig(pool_sharding)
+        jit_fn = self._step_jits.get(key)
+        if jit_fn is None:
+            # donate the pools: the backend unconditionally replaces its
+            # references with the returned pools, so input and output may
+            # alias (per shard, on a mesh)
+            kw = dict(static_argnames=("kernel_mode",),
+                      donate_argnums=(2, 3))
+            impl = self._step_paged_impl
+            if pool_sharding is not None:
+                repl = jax.sharding.NamedSharding(
+                    pool_sharding.mesh, jax.sharding.PartitionSpec())
+                kw["out_shardings"] = (repl, repl, pool_sharding,
+                                       pool_sharding)
+                impl = functools.partial(impl, mesh=pool_sharding.mesh)
+            jit_fn = self._step_jits[key] = jax.jit(impl, **kw)
+        return key, jit_fn
+
+    def lower_step_paged(self, *args, kernel_mode: str,
+                         pool_sharding=None):
+        """Lower the fused step for `step_paged`'s positional args (arrays
+        or ShapeDtypeStructs) without running it or touching the compile
+        census.  ``.compile().memory_analysis()`` of the result is the
+        step's device memory at that shape bucket."""
+        return self._step_jit(pool_sharding)[1].lower(
+            *args, kernel_mode=kernel_mode)
+
     @staticmethod
     def _scatter_paged_impl(k_pool, v_pool, layer_ids, pages, offs, ks, vs):
-        return (k_pool.at[layer_ids, pages, offs].set(ks),
-                v_pool.at[layer_ids, pages, offs].set(vs))
+        return (k_pool.at[layer_ids, pages, :, offs].set(ks),
+                v_pool.at[layer_ids, pages, :, offs].set(vs))
 
     def scatter_paged(self, k_pool, v_pool, layer_ids, pages, offs, ks, vs,
                       pool_sharding=None):

@@ -9,7 +9,7 @@ identical in both modes:
   This is the discrete-event simulator's backend and reproduces the paper's
   cluster-scale numbers.
 * `RealBackend` — owns ONE stacked physical page pool per side
-  ((L, P+1, page, Hkv, D) jnp arrays standing in for HBM; page index P is a
+  ((L, P+1, Hkv, page, D) jnp arrays standing in for HBM; page index P is a
   trash page for padded-lane scatter), plus a numpy host staging tier and an
   optional .npz disk spool, and executes one engine iteration as ONE fused,
   recompile-free dispatch: a MIXED batch where each lane carries
@@ -292,7 +292,7 @@ def _bucket(n: int, floor: int = 1) -> int:
 class RealBackend(Backend):
     """Real JAX execution over a stacked paged KV pool.
 
-    The "HBM" tier is one (L, P+1, page, Hkv, D) jnp pool per side (page
+    The "HBM" tier is one (L, P+1, Hkv, page, D) jnp pool per side (page
     index P is the trash page that padded lanes scatter into — it is never
     allocated or gathered); the host tier is numpy arrays keyed (sid,
     layer) — or `PendingPayload` futures while a device->host copy is in
@@ -327,7 +327,10 @@ class RealBackend(Backend):
     GQA), params get the Megatron column/row specs, and every
     `step_paged` / `scatter_paged` / `fork_paged` dispatch is a sharded jit
     whose out_shardings pin the pool placement so donation still aliases
-    per shard.  Tier movement is PER-SHARD: the eager gather produces a
+    per shard.  A Pallas kernel mode runs the attention kernel per shard
+    (`jax.shard_map`), so it needs the kv-head split: the split-K
+    fallback serves only through the ``ref`` oracle and a Pallas mode
+    there raises.  Tier movement is PER-SHARD: the eager gather produces a
     sharded array whose `copy_to_host_async` launches tp independent
     device->host copies, and `np.asarray` assembles the full-head host
     payload — host/spool/export formats are therefore pre-concatenated and
@@ -363,8 +366,6 @@ class RealBackend(Backend):
         self.mesh = mesh
         self.tp = 1
         self._pool_sharding = None
-        self.kernel_mode = serving_kernel_mode(kernel_mode,
-                                               meshed=mesh is not None)
         self.trace_logits = trace_logits
         # context-aware lane packing: when the bucketed table-width skew
         # (widest lane's bucket over the median lane's bucket) reaches this
@@ -374,7 +375,7 @@ class RealBackend(Backend):
         self.split_skew = float(split_skew)
         self.dtype = jnp.dtype(cfg.dtype)
         L, Hkv, D = cfg.n_layers, cfg.n_kv_heads, cfg.d_head
-        shape = (L, n_pages + 1, page_size, Hkv, D)
+        shape = (L, n_pages + 1, Hkv, page_size, D)
         self.k_pool = jnp.zeros(shape, self.dtype)
         self.v_pool = jnp.zeros(shape, self.dtype)
         # quantized-in-HBM tier: int8 shadow pools + per-(layer, page)
@@ -400,6 +401,10 @@ class RealBackend(Backend):
             # is not divisible falls back to replication per the plan
             self.params = jax.device_put(
                 params, plan.params_shardings(params))
+        self.kernel_mode = serving_kernel_mode(
+            kernel_mode, meshed=mesh is not None,
+            heads_sharded=(mesh is None
+                           or self._pool_sharding.spec[2] == "model"))
         self.alloc: List[PagedAllocator] = [
             PagedAllocator(n_pages, page_size) for _ in range(L)]
         self.host: Dict[Tuple[str, int], object] = {}  # (sid, layer) ->
@@ -430,6 +435,21 @@ class RealBackend(Backend):
         signature, so two mesh shapes with identical bucket signatures
         count separately instead of silently colliding."""
         return self.model.paged_compile_counts()
+
+    def step_memory(self, lanes: int, tokens: int, width: int):
+        """`memory_analysis()` of the fused step this backend dispatches
+        at one (lanes, tokens-per-step, table-width) bucket, with its
+        current pools and quant tier: a compile, not a run."""
+        import jax
+        import jax.numpy as jnp
+        L = self.cfg.n_layers
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        lowered = self.model.lower_step_paged(
+            self.params, i32(lanes, tokens), self.k_pool, self.v_pool,
+            i32(L, lanes, width), i32(lanes), i32(lanes), i32(lanes),
+            i32(L, lanes, tokens), i32(L, lanes, tokens), self._quant_args(),
+            kernel_mode=self.kernel_mode, pool_sharding=self._pool_sharding)
+        return lowered.compile().memory_analysis()
 
     def attach(self, mgr) -> None:
         """Bidirectional wiring: manager promote/evict trigger real copies."""
@@ -529,8 +549,8 @@ class RealBackend(Backend):
         import jax
         import jax.numpy as jnp
         c = self.cfg
-        shape = (c.n_layers, self.n_pages + 1, self.page_size,
-                 c.n_kv_heads, c.d_head)
+        shape = (c.n_layers, self.n_pages + 1, c.n_kv_heads,
+                 self.page_size, c.d_head)
         self.kq_pool = jnp.zeros(shape, jnp.int8)
         self.vq_pool = jnp.zeros(shape, jnp.int8)
         self.k_scale = jnp.zeros(shape[:2], jnp.float32)
@@ -747,6 +767,8 @@ class RealBackend(Backend):
         Returns (groups, empties): in-flight device arrays and
         already-realized zero-page payloads."""
         import jax.numpy as jnp
+
+        from repro.kernels.paged_attention import pages_to_tokens
         c = self.cfg
         groups, empties = [], {}
         by: Dict[Tuple[int, int], List[int]] = {}
@@ -781,10 +803,8 @@ class RealBackend(Backend):
                         jnp.float32) * ks).astype(self.dtype), k)
                     v = jnp.where(isq, (self.vq_pool[li, pi].astype(
                         jnp.float32) * vs).astype(self.dtype), v)
-            k = k.reshape(
-                len(ls), npg * self.page_size, c.n_kv_heads, c.d_head)[:, :n]
-            v = v.reshape(
-                len(ls), npg * self.page_size, c.n_kv_heads, c.d_head)[:, :n]
+            k = pages_to_tokens(k)[:, :n]
+            v = pages_to_tokens(v)[:, :n]
             k.copy_to_host_async()
             v.copy_to_host_async()
             groups.append(dict(layers=ls, n=n, k=k, v=v))

@@ -7,7 +7,7 @@ out per-sequence block tables; the TieredKVStore (core/memory.py) tracks
 which tier each (session, layer) page group lives in.
 
 Design notes vs the GPU original (DESIGN.md §3): the pool is a dense
-(P, page_size, Hkv, D) array per layer — static shape for XLA — and the
+(P, Hkv, page_size, D) array per layer — static shape for XLA — and the
 block table is the only indirection; copy-on-migrate swaps page *contents*,
 never remaps live tables mid-step (tables are step inputs).
 

@@ -14,7 +14,8 @@ disk write-through, crash recovery) drive all three state kinds unchanged:
   slot padded lanes read/write — handed out by a `StateAllocator` (one
   fixed slot per resident session; same lease/conservation discipline as
   page allocation).  Hybrid configs add per-application paged KV pools
-  ((n_apps, n_pages + 1, page, Hkv, D)) with lockstep `PagedAllocator`s.
+  ((n_apps, n_pages + 1, Hkv, page, D), head-major) with lockstep
+  `PagedAllocator`s.
 * One engine iteration is ONE fused `step_slots` dispatch: every lane
   gathers its slot, runs the masked-exact chunked scan over its (padded)
   token slice, and scatters the advanced state back — decode lanes are
@@ -82,8 +83,8 @@ class StateBackend(Backend):
                                     for p in self.pools.values()))
         if self.has_kv:
             self.n_apps = model.n_groups_outer
-            shape = (self.n_apps, n_pages + 1, page_size,
-                     cfg.n_kv_heads, cfg.d_head)
+            shape = (self.n_apps, n_pages + 1, cfg.n_kv_heads,
+                     page_size, cfg.d_head)
             self.k_pool = jnp.zeros(shape, self.dtype)
             self.v_pool = jnp.zeros(shape, self.dtype)
             self.kv_alloc: List[PagedAllocator] = [
@@ -197,6 +198,8 @@ class StateBackend(Backend):
         ``live`` distinguishes in-flight device arrays from the realized
         zero-page case."""
         import jax.numpy as jnp
+
+        from repro.kernels.paged_attention import pages_to_tokens
         if not self.has_kv or sid not in self.kv_alloc[0].seqs:
             return None
         c = self.cfg
@@ -210,10 +213,8 @@ class StateBackend(Backend):
         pi = jnp.asarray(np.stack(
             [self.kv_alloc[a].seqs[sid].pages
              for a in range(self.n_apps)]), jnp.int32)
-        k = self.k_pool[ai, pi].reshape(
-            self.n_apps, npg * self.page_size, c.n_kv_heads, c.d_head)[:, :n]
-        v = self.v_pool[ai, pi].reshape(
-            self.n_apps, npg * self.page_size, c.n_kv_heads, c.d_head)[:, :n]
+        k = pages_to_tokens(self.k_pool[ai, pi])[:, :n]
+        v = pages_to_tokens(self.v_pool[ai, pi])[:, :n]
         k.copy_to_host_async()
         v.copy_to_host_async()
         return dict(k=k, v=v, n_tokens=n, live=True)

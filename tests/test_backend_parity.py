@@ -113,6 +113,24 @@ def test_multiturn_parity_with_preemption(kind):
         np.testing.assert_allclose(got_lg, want_lg, **TOL)
 
 
+@pytest.mark.parametrize("kind", ["mha", "gqa"])
+def test_served_logits_match_float32_reference(kind):
+    """The plain float32 reference (models/reference.py), which the chip
+    smoke holds the served bf16 path to at published widths, agrees here
+    with the served f32 path at every generated position of a two-turn
+    conversation: one full-sequence forward pass, no cache."""
+    from repro.models.reference import dense_logits
+    cfg, model, params, mgr, be, eng = _setup(kind)
+    turns = _turns(cfg, (11, 7), seed=2)
+    (o1, o2) = _serve(eng, be, turns)
+    seq = turns[0] + o1 + turns[1] + o2[:-1]
+    at = [len(turns[0]) - 1 + i for i in range(GEN)] + \
+        [len(turns[0]) + GEN + len(turns[1]) - 1 + i for i in range(GEN)]
+    want = dense_logits(cfg, params, seq, at)
+    got = np.stack([lg for _sid, lg in be.logit_trace])
+    np.testing.assert_allclose(got, want, **TOL)
+
+
 def test_cooperative_evict_then_promote_preserves_kv():
     """Layer-granular eviction (node-manager cooperative purge) followed by
     priority promotion must physically round-trip page contents."""

@@ -19,8 +19,8 @@ TOL32 = dict(rtol=2e-4, atol=2e-4)
 def _mk_paged(rng, B, H, Hkv, D, page, maxp, dtype):
     P = maxp * B + 2
     q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
     tables = jnp.asarray(
         rng.permutation(P)[:B * maxp].reshape(B, maxp), jnp.int32)
     ctx = jnp.asarray(rng.integers(1, maxp * page + 1, (B,)), jnp.int32)
@@ -76,8 +76,8 @@ def test_paged_attention_page_permutation_invariance():
 def _mk_chunk(rng, B, Sq, H, Hkv, D, page, maxp, dtype, decode=False):
     P = maxp * B + 2
     q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
     tables = jnp.asarray(
         rng.permutation(P)[:B * maxp].reshape(B, maxp), jnp.int32)
     if decode:                                  # q_len 1: qoff = ctx - 1

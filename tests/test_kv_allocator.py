@@ -68,8 +68,8 @@ def test_block_tables_drive_paged_kernel():
     a.allocate("y", 7)
     tables = jnp.asarray(a.batch_block_tables(["x", "y"]))
     ctx = jnp.asarray(a.ctx_lens(["x", "y"]))
-    kp = jnp.asarray(rng.normal(size=(12, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(12, page, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(12, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(12, Hkv, page, D)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(2, H, D)), jnp.float32)
     out = paged_attention(q, kp, vp, tables, ctx, interpret=True)
     want = paged_attention_ref(q, kp, vp, tables, ctx)

@@ -129,8 +129,8 @@ def _kernel_case(kind: str, seed: int = 0):
     H, D, P, maxp, B, Sq = 4, 16, 6, 2, 2, 8
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (P, PAGE, Hkv, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (P, PAGE, Hkv, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (P, Hkv, PAGE, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (P, Hkv, PAGE, D), jnp.float32)
     tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     q_off = jnp.asarray([3, 8], jnp.int32)
     ctx = q_off + Sq

@@ -102,25 +102,25 @@ def _serve(eng, be, turns, gen=GEN, preempt_turn=None, sid="s0", cached=0):
 
 @needs4
 def test_pool_spec_divisibility_ladder():
-    """(L, P+1, page, Hkv, D) pool: Hkv -> model when divisible, else the
+    """(L, P+1, Hkv, page, D) pool: Hkv -> model when divisible, else the
     page-slot split-K fallback, else D, else replicate; the layer and
     page-index dims are never sharded (block tables are global)."""
     cfg = _cfg("mha")
     p2 = ShardingPlan(cfg, make_serving_mesh(tp=2))
     p4 = ShardingPlan(cfg, make_serving_mesh(tp=4))
     # Hkv=4 divides both
-    assert p2.pool_spec((4, 33, 8, 4, 16)) == P(None, None, None, "model",
+    assert p2.pool_spec((4, 33, 4, 8, 16)) == P(None, None, "model", None,
                                                 None)
-    assert p4.pool_spec((4, 33, 8, 4, 16)) == P(None, None, None, "model",
+    assert p4.pool_spec((4, 33, 4, 8, 16)) == P(None, None, "model", None,
                                                 None)
     # Hkv=2 at tp=4: split-K on the page-slot dim
-    assert p4.pool_spec((4, 33, 8, 2, 16)) == P(None, None, "model", None,
+    assert p4.pool_spec((4, 33, 2, 8, 16)) == P(None, None, None, "model",
                                                 None)
     # page=6 indivisible too: the head-feature dim
-    assert p4.pool_spec((4, 33, 6, 2, 16)) == P(None, None, None, None,
+    assert p4.pool_spec((4, 33, 2, 6, 16)) == P(None, None, None, None,
                                                 "model")
     # nothing divisible: fully replicated
-    assert p4.pool_spec((4, 33, 6, 2, 6)) == P(None, None, None, None, None)
+    assert p4.pool_spec((4, 33, 2, 6, 6)) == P(None, None, None, None, None)
     # same ladder in cache_spec's kv-like branch, on a model-only mesh
     # (no data axis present -> it must never name "data")
     assert p4.cache_spec("k", (4, 1, 8, 2, 16)) == P(None, None, "model",
@@ -161,11 +161,38 @@ def test_parity_across_tp_with_preemption(kind):
     for tp in (1, 2, 4):
         _, _, _, _, be, eng = _setup(kind, tp=tp)
         if kind == "gqa" and tp == 4:
-            assert be._pool_sharding.spec == P(None, None, "model", None,
+            assert be._pool_sharding.spec == P(None, None, None, "model",
                                                None)
         got = _serve(eng, be, turns, preempt_turn=1)
         assert got == want, f"token divergence ({kind}, tp={tp})"
         assert be.stats["swaps_out"] >= 1 and be.stats["swaps_in"] >= 1
+
+
+@needs4
+def test_pallas_kernel_runs_per_shard_under_shard_map():
+    """A Pallas kernel mode on a mesh runs the attention kernel per kv-head
+    shard under `jax.shard_map` (a Mosaic kernel cannot be partitioned by
+    the compiler): tp=2 interpret-mode serving, through a swap round trip
+    and a quantized turn (the int8 kernel variant), emits exactly the
+    single-device tokens.  A split-K pool has no per-shard kernel, so a
+    Pallas mode there raises instead of serving from the oracle."""
+    cfg = _cfg("mha")
+    turns = _turns(cfg, (19, 5), seed=7)
+    got = {}
+    for tp in (None, 2):
+        _, _, _, _, be, eng = _setup("mha", tp=tp, kernel_mode="interpret")
+        assert be.kernel_mode == "interpret"
+        outs = _serve(eng, be, turns[:1])
+        be.swap_out("s0", be.session_tokens("s0"))
+        be.drain_transfers()
+        outs += _serve(eng, be, turns[1:], cached=be.session_tokens("s0"))
+        assert be.quantize_session("s0") > 0
+        outs += _serve(eng, be, turns[:1], cached=be.session_tokens("s0"))
+        assert be.stats["swaps_in"] >= 1 and be.stats["quant_dispatches"]
+        got[tp] = outs
+    assert got[2] == got[None]
+    with pytest.raises(ValueError, match="kv-heads"):
+        _setup("gqa", tp=4, kernel_mode="interpret")
 
 
 # ---------------------------------------------------------------------------
