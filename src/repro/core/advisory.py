@@ -47,6 +47,10 @@ class InferenceRequest:
     finished_at: Optional[float] = None
     generated: int = 0
     cached_tokens: int = 0                      # session KV available at arrival
+    # host time.perf_counter() when an engine first queued and first
+    # admitted the request; a preempted request keeps both
+    queued_at: Optional[float] = None
+    admitted_at: Optional[float] = None
 
     @property
     def ttft(self) -> Optional[float]:

@@ -282,33 +282,43 @@ class DenseLM:
                 # compress_paged ever writes them
                 w, kp, vp, table, sp, so, kq, vq, ks, vs, pq = xs
                 qt = (kq, vq, ks, vs, pq)
-            h = L.rms_norm(x, w["ln1"], c.norm_eps)
-            q = (h @ w["wq"]).reshape(B, Sq, c.n_heads, c.d_head)
-            k = (h @ w["wk"]).reshape(B, Sq, c.n_kv_heads, c.d_head)
-            v = (h @ w["wv"]).reshape(B, Sq, c.n_kv_heads, c.d_head)
-            if c.qk_norm:
-                q = L.rms_norm(q, w["qn"], c.norm_eps)
-                k = L.rms_norm(k, w["kn"], c.norm_eps)
-            q = L.apply_rope(q, positions, c.rope_theta)
-            k = L.apply_rope(k, positions, c.rope_theta)
-            kp = kp.at[sp, :, so].set(k.astype(kp.dtype))
-            vp = vp.at[sp, :, so].set(v.astype(vp.dtype))
-            o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets,
-                                          ctx_lens, mode=kernel_mode,
-                                          quant=qt, mesh=mesh)
-            x = x + o.reshape(B, Sq, -1) @ w["wo"]
-            h2 = L.rms_norm(x, w["ln2"], c.norm_eps)
-            x = x + L.swiglu(h2, w["w1"], w["w3"], w["w2"])
+            with jax.named_scope("attn"):
+                h = L.rms_norm(x, w["ln1"], c.norm_eps)
+                q = (h @ w["wq"]).reshape(B, Sq, c.n_heads, c.d_head)
+                k = (h @ w["wk"]).reshape(B, Sq, c.n_kv_heads, c.d_head)
+                v = (h @ w["wv"]).reshape(B, Sq, c.n_kv_heads, c.d_head)
+                if c.qk_norm:
+                    q = L.rms_norm(q, w["qn"], c.norm_eps)
+                    k = L.rms_norm(k, w["kn"], c.norm_eps)
+                q = L.apply_rope(q, positions, c.rope_theta)
+                k = L.apply_rope(k, positions, c.rope_theta)
+            with jax.named_scope("kv_write"):
+                kp = kp.at[sp, :, so].set(k.astype(kp.dtype))
+                vp = vp.at[sp, :, so].set(v.astype(vp.dtype))
+            with jax.named_scope("attn"):
+                o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets,
+                                              ctx_lens, mode=kernel_mode,
+                                              quant=qt, mesh=mesh)
+                x = x + o.reshape(B, Sq, -1) @ w["wo"]
+            with jax.named_scope("ffn"):
+                h2 = L.rms_norm(x, w["ln2"], c.norm_eps)
+                x = x + L.swiglu(h2, w["w1"], w["w3"], w["w2"])
             return x, (kp, vp)
 
         xs = (params["blocks"], k_pool, v_pool, tables,
               slot_pages, slot_offs)
         if quant is not None:
             xs = xs + tuple(quant)
-        x, (k_pool, v_pool) = jax.lax.scan(body, x, xs)
-        x = L.rms_norm(x, params["ln_f"], c.norm_eps)
-        logits = self._unembed(params, x[jnp.arange(B), last_idx])
-        toks = jnp.argmax(logits[:, :c.vocab], axis=-1).astype(jnp.int32)
+        # device scopes: ops under `layers` in none of the body's scopes
+        # are the scan's own slicing of its inputs and stacking of its
+        # outputs, the KV pools above all
+        with jax.named_scope("layers"):
+            x, (k_pool, v_pool) = jax.lax.scan(body, x, xs)
+        with jax.named_scope("head"):
+            x = L.rms_norm(x, params["ln_f"], c.norm_eps)
+            logits = self._unembed(params, x[jnp.arange(B), last_idx])
+            toks = jnp.argmax(logits[:, :c.vocab],
+                              axis=-1).astype(jnp.int32)
         return toks, logits, k_pool, v_pool
 
     @staticmethod

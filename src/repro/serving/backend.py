@@ -84,6 +84,7 @@ import numpy as np
 from repro.core.memory import PrefixIndex
 from repro.serving.cost_model import CostModel
 from repro.serving.kv_cache import OutOfPages, PagedAllocator
+from repro.serving.tracing import span
 from repro.serving.transfer import (IN, OUT, PERSIST, PendingPayload,
                                     Transfer, TransferEngine)
 
@@ -281,6 +282,11 @@ class _SeqState:
     ids: List[int] = field(default_factory=list)
 
 
+def _group_bytes(groups) -> int:
+    """Bytes of the K and V slices `RealBackend._gather_device` launched."""
+    return sum(g["k"].nbytes + g["v"].nbytes for g in groups)
+
+
 def _bucket(n: int, floor: int = 1) -> int:
     """Smallest power-of-two >= n (and >= floor): the shape-bucket lattice."""
     b = floor
@@ -424,7 +430,8 @@ class RealBackend(Backend):
                           quantized_pages=0, quant_dispatches=0,
                           dequant_forks=0, admit_quantized=0,
                           sub_dispatches=0, split_steps=0,
-                          dma_pages=0, grid_pages=0)
+                          dma_pages=0, grid_pages=0,
+                          live_rows=0, padded_rows=0)
         self.logit_trace: List[Tuple[str, np.ndarray]] = []
 
     def compile_counts(self) -> Dict[str, int]:
@@ -827,7 +834,9 @@ class RealBackend(Backend):
         return to the free list and store accounting moves HBM->HOST only
         when the copy lands (a failed or preempted transfer never loses
         KV).  Zero-page layers complete inline."""
-        groups, empties = self._gather_device(sid, layers)
+        with span("transfer.launch", kind=OUT) as sp:
+            groups, empties = self._gather_device(sid, layers)
+            sp.set_metadata(bytes=_group_bytes(groups))
         leases = {l: self.alloc[l].lease(sid) for l in layers}
 
         def _bookkeep(done_layers):
@@ -848,8 +857,7 @@ class RealBackend(Backend):
             return
 
         tr = Transfer(sid, OUT, [a for g in groups for a in (g["k"], g["v"])],
-                      nbytes=float(sum(g["k"].nbytes + g["v"].nbytes
-                                       for g in groups)))
+                      nbytes=float(_group_bytes(groups)))
         pendings: Dict[int, PendingPayload] = {}
         for g in groups:
             for l in g["layers"]:
@@ -892,25 +900,27 @@ class RealBackend(Backend):
         if not groups:
             return
         nbytes = 0.0
-        for n, ls in groups.items():
-            G, Gb, nb = len(ls), _bucket(len(ls)), _bucket(n)
-            li = np.zeros((Gb, 1), np.int32)
-            pg = np.full((Gb, nb), self.n_pages, np.int32)   # pad -> trash
-            off = np.zeros((Gb, nb), np.int32)
-            ks = np.zeros((Gb, nb, c.n_kv_heads, c.d_head), self.dtype)
-            vs = np.zeros_like(ks)
-            for i, l in enumerate(ls):
-                li[i, 0] = l
-                p, o = self._slots(l, sid, 0, n)
-                pg[i, :n] = p
-                off[i, :n] = o
-                ks[i, :n] = payloads[l]["k"]
-                vs[i, :n] = payloads[l]["v"]
-            self.k_pool, self.v_pool = self.model.scatter_paged(
-                self.k_pool, self.v_pool, jnp.asarray(li), jnp.asarray(pg),
-                jnp.asarray(off), jnp.asarray(ks), jnp.asarray(vs),
-                pool_sharding=self._pool_sharding)
-            nbytes += float(ks[:G, :n].nbytes + vs[:G, :n].nbytes)
+        with span("transfer.launch", kind=IN) as sp:
+            for n, ls in groups.items():
+                G, Gb, nb = len(ls), _bucket(len(ls)), _bucket(n)
+                li = np.zeros((Gb, 1), np.int32)
+                pg = np.full((Gb, nb), self.n_pages, np.int32)   # pad -> trash
+                off = np.zeros((Gb, nb), np.int32)
+                ks = np.zeros((Gb, nb, c.n_kv_heads, c.d_head), self.dtype)
+                vs = np.zeros_like(ks)
+                for i, l in enumerate(ls):
+                    li[i, 0] = l
+                    p, o = self._slots(l, sid, 0, n)
+                    pg[i, :n] = p
+                    off[i, :n] = o
+                    ks[i, :n] = payloads[l]["k"]
+                    vs[i, :n] = payloads[l]["v"]
+                self.k_pool, self.v_pool = self.model.scatter_paged(
+                    self.k_pool, self.v_pool, jnp.asarray(li), jnp.asarray(pg),
+                    jnp.asarray(off), jnp.asarray(ks), jnp.asarray(vs),
+                    pool_sharding=self._pool_sharding)
+                nbytes += float(ks[:G, :n].nbytes + vs[:G, :n].nbytes)
+            sp.set_metadata(bytes=int(nbytes))
         # the transfer must NOT hold the pools themselves: every subsequent
         # step_paged/scatter_paged DONATES them, deleting the arrays under
         # the in-flight future.  Track tiny sentinel slices instead — each
@@ -1119,73 +1129,79 @@ class RealBackend(Backend):
         L = self.cfg.n_layers
         B = len(sids)
         q_lens = [len(ids) for ids in ids_by_lane]
-        # tokens-per-step bucket: pure-decode groups sit at Sq = 1; chunked
-        # groups land on the power-of-two lattice.  No floor — the engine's
-        # token budget already controls the chunk-size lattice, and every
-        # lane in the group pays Sqb query rows, so padding small chunks up
-        # to 8 would tax the decode lanes riding the same dispatch
-        Sqb = _bucket(max(q_lens))
-        Bb = _bucket(B)                          # lane-count shape bucket
-        Tb = _bucket(max(len(self.alloc[0].seqs[s].pages) for s in sids))
-        ids_p = np.zeros((Bb, Sqb), np.int32)
-        qoff = np.zeros((Bb,), np.int32)
-        ctx = np.zeros((Bb,), np.int32)          # padded lanes: ctx 0 -> masked
-        last = np.zeros((Bb,), np.int32)
-        tables = np.zeros((L, Bb, Tb), np.int32)
-        # padded slots scatter into the trash page (index n_pages)
-        pg = np.full((L, Bb, Sqb), self.n_pages, np.int32)
-        off = np.zeros((L, Bb, Sqb), np.int32)
-        for i, (sid, ids) in enumerate(zip(sids, ids_by_lane)):
-            st = self.seqs[sid]
-            n = len(ids)
-            ids_p[i, :n] = ids
-            qoff[i] = st.n_kv
-            ctx[i] = st.n_kv + n
-            last[i] = n - 1
-            # one (L, w) page-id matrix per lane collapses the old
-            # per-layer Python loops into numpy gathers: the block-table
-            # fill and the KV slot mapping (same write positions in every
-            # layer) both read it
-            pages = np.asarray([self.alloc[l].seqs[sid].pages
-                                for l in range(L)], np.int32)
-            w = pages.shape[1]
-            tables[:, i, :w] = pages
-            # pad table columns with the lane's LAST VALID page id (never
-            # 0): the kernel's clamped index maps keep the block index
-            # constant across the tail, so the padded walk costs no DMA —
-            # see the paged_attention module docstring for the invariant
-            if w:
-                tables[:, i, w:] = pages[:, -1:]
-            pos = st.n_kv + np.arange(n)
-            pg[:, i, :n] = pages[:, pos // self.page_size]
-            off[:, i, :n] = pos % self.page_size
-        # page-walk accounting (per kv head): the elided kernel fetches
-        # each lane's own relevant pages; the grid still walks the full
-        # (Bb, Tb) bucket, compute-masked and DMA-elided
-        self.stats["dma_pages"] += int(
-            sum(-(-int(c) // self.page_size) for c in ctx[:B]))
-        self.stats["grid_pages"] += Bb * Tb
-        self.stats["sub_dispatches"] += 1
-        toks_dev, logits, self.k_pool, self.v_pool = self.model.step_paged(
-            self.params, ids_p, self.k_pool, self.v_pool, tables,
-            jnp.asarray(qoff), jnp.asarray(ctx), jnp.asarray(last), pg, off,
-            quant=quant, kernel_mode=self.kernel_mode,
-            pool_sharding=self._pool_sharding)
-        tok_np = np.asarray(toks_dev[:B])        # token ids only — no full-
-        lg_np = None                             # logits sync unless tracing
-        if self.trace_logits:
-            lg_np = np.asarray(logits[:B, :self.cfg.vocab])
+        rows = sum(q_lens)
+        with span("backend.assemble"):
+            # tokens-per-step bucket: pure-decode groups sit at Sq = 1;
+            # chunked groups land on the power-of-two lattice.  No floor —
+            # the engine's token budget already controls the chunk-size
+            # lattice, and every lane in the group pays Sqb query rows, so
+            # padding small chunks up to 8 would tax the decode lanes
+            # riding the same dispatch
+            Sqb = _bucket(max(q_lens))
+            Bb = _bucket(B)                      # lane-count shape bucket
+            Tb = _bucket(max(len(self.alloc[0].seqs[s].pages)
+                             for s in sids))
+            ids_p = np.zeros((Bb, Sqb), np.int32)
+            qoff = np.zeros((Bb,), np.int32)
+            ctx = np.zeros((Bb,), np.int32)      # padded lanes: ctx 0 -> masked
+            last = np.zeros((Bb,), np.int32)
+            tables = np.zeros((L, Bb, Tb), np.int32)
+            # padded slots scatter into the trash page (index n_pages)
+            pg = np.full((L, Bb, Sqb), self.n_pages, np.int32)
+            off = np.zeros((L, Bb, Sqb), np.int32)
+            for i, (sid, ids) in enumerate(zip(sids, ids_by_lane)):
+                st = self.seqs[sid]
+                n = len(ids)
+                ids_p[i, :n] = ids
+                qoff[i] = st.n_kv
+                ctx[i] = st.n_kv + n
+                last[i] = n - 1
+                # one (L, w) page-id matrix per lane collapses the old
+                # per-layer Python loops into numpy gathers: the block-
+                # table fill and the KV slot mapping (same write positions
+                # in every layer) both read it
+                pages = np.asarray([self.alloc[l].seqs[sid].pages
+                                    for l in range(L)], np.int32)
+                w = pages.shape[1]
+                tables[:, i, :w] = pages
+                # pad table columns with the lane's LAST VALID page id
+                # (never 0): the kernel's clamped index maps keep the block
+                # index constant across the tail, so the padded walk costs
+                # no DMA — see the paged_attention module docstring for the
+                # invariant
+                if w:
+                    tables[:, i, w:] = pages[:, -1:]
+                pos = st.n_kv + np.arange(n)
+                pg[:, i, :n] = pages[:, pos // self.page_size]
+                off[:, i, :n] = pos % self.page_size
+            # page-walk accounting (per kv head): the elided kernel fetches
+            # each lane's own relevant pages; the grid still walks the full
+            # (Bb, Tb) bucket, compute-masked and DMA-elided
+            self.stats["dma_pages"] += int(
+                sum(-(-int(c) // self.page_size) for c in ctx[:B]))
+            self.stats["grid_pages"] += Bb * Tb
+            # query rows: the FFN and projections run over all Bb x Sqb
+            self.stats["live_rows"] += rows
+            self.stats["padded_rows"] += Bb * Sqb
+            self.stats["sub_dispatches"] += 1
+        with span("backend.dispatch", Bb=Bb, Sqb=Sqb, Tb=Tb, lanes=B,
+                  rows=rows):
+            toks_dev, logits, self.k_pool, self.v_pool = \
+                self.model.step_paged(
+                    self.params, ids_p, self.k_pool, self.v_pool, tables,
+                    jnp.asarray(qoff), jnp.asarray(ctx), jnp.asarray(last),
+                    pg, off, quant=quant, kernel_mode=self.kernel_mode,
+                    pool_sharding=self._pool_sharding)
+        with span("backend.fetch"):
+            tok_np = np.asarray(toks_dev[:B])    # token ids only — no full-
+            lg_np = None                         # logits sync unless tracing
+            if self.trace_logits:
+                lg_np = np.asarray(logits[:B, :self.cfg.vocab])
         return tok_np, lg_np
 
-    def step(self, lanes, now) -> StepResult:
-        import jax.numpy as jnp
-        # reap ready transfers BEFORE the timed region: a pending persist's
-        # np.savez is background work and must not inflate this step's
-        # measured duration (the TBT percentiles CI gates)
-        self.transfers.poll()
-        t0 = time.perf_counter()
-        # tier fetch first (timed: swap-ins during decode are stall, not
-        # compute — they used to vanish from stall accounting entirely)
+    def _make_resident(self, lanes) -> None:
+        """Tier fetch for every lane before anything is built: swap-ins
+        during decode are stall, not compute (`StepResult.stall`)."""
         for ln in lanes:
             sid = ln.req.session_id
             if ln.req.output_ids is None:
@@ -1211,8 +1227,11 @@ class RealBackend(Backend):
         # ~0 when the advisory led admission by enough
         for ln in lanes:
             self.transfers.fence(sid=ln.req.session_id, kind=IN)
-        t_resident = time.perf_counter()
 
+    def _grow(self, lanes) -> Tuple[List[str], List[List[int]]]:
+        """Each lane's token ids, and its pages grown to hold them (CoW
+        forks dispatched first).  Returns (session ids, ids by lane)."""
+        import jax.numpy as jnp
         ids_by_lane = [self._lane_ids(ln) for ln in lanes]
         for ln, ids in zip(lanes, ids_by_lane):
             if not ids:
@@ -1287,6 +1306,19 @@ class RealBackend(Backend):
                     pool_sharding=self._pool_sharding)
         for sid, ids in zip(sids, ids_by_lane):
             self._extend_all(sid, len(ids))
+        return sids, ids_by_lane
+
+    def step(self, lanes, now) -> StepResult:
+        # reap ready transfers BEFORE the timed region: a pending persist's
+        # np.savez is background work and must not inflate this step's
+        # measured duration (the TBT percentiles CI gates)
+        self.transfers.poll()
+        t0 = time.perf_counter()
+        with span("backend.resident"):
+            self._make_resident(lanes)
+        t_resident = time.perf_counter()
+        with span("backend.grow"):
+            sids, ids_by_lane = self._grow(lanes)
 
         B = len(lanes)
         # per-lane table widths from LAYER 0 ONLY: _ensure_resident and
@@ -1484,7 +1516,9 @@ class RealBackend(Backend):
                 staged.append(l)
             else:
                 return False               # a layer is unreachable: no copy
-        groups, empties = self._gather_device(sid, resident)
+        with span("transfer.launch", kind=PERSIST) as sp:
+            groups, empties = self._gather_device(sid, resident)
+            sp.set_metadata(bytes=_group_bytes(groups))
         staged_refs = {l: self.host[(sid, l)] for l in staged}
         # the pending token has no KV anywhere — it must ride along in the
         # spool or a post-crash recovery cannot resume the sequence
@@ -1516,8 +1550,7 @@ class RealBackend(Backend):
 
         self.transfers.launch(Transfer(
             sid, PERSIST, [a for g in groups for a in (g["k"], g["v"])],
-            on_complete=_complete,
-            nbytes=float(sum(g["k"].nbytes + g["v"].nbytes for g in groups))))
+            on_complete=_complete, nbytes=float(_group_bytes(groups))))
         return True
 
     # -- peer migration (the advisory path, real copies) --------------------

@@ -37,6 +37,7 @@ Key behaviours under test:
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, List, Optional, Tuple
@@ -46,6 +47,7 @@ from repro.core.node_manager import NodeManager
 from repro.serving.backend import Backend, LaneWork, SimBackend, StepResult
 from repro.serving.cost_model import CostModel
 from repro.serving.kv_cache import OutOfPages
+from repro.serving.tracing import recording, span
 from repro.serving.transfer import OUT
 
 
@@ -81,12 +83,13 @@ class NodeEngine:
         self.completed: List[InferenceRequest] = []
         self.stats = dict(prefill_tokens=0, redundant_tokens=0,
                           decode_steps=0, preemptions=0, stall_s=0.0,
-                          busy_s=0.0, chunks=0, admission_skips=0,
-                          shared_prefix_tokens=0)
+                          busy_s=0.0, chunks=0, shared_prefix_tokens=0)
 
     # -- queue interface ----------------------------------------------------------
 
     def submit(self, req: InferenceRequest) -> None:
+        if req.queued_at is None:
+            req.queued_at = time.perf_counter()
         if req.priority > 0:
             self.waiting.appendleft(req)
         else:
@@ -110,11 +113,16 @@ class NodeEngine:
     def step(self, now: float) -> float:
         """Run one token-budget iteration; returns its duration (sim or
         wall seconds)."""
+        with span("engine.step") as sp:
+            return self._step(now, sp)
+
+    def _step(self, now: float, sp) -> float:
         # reap finished async tier transfers (non-blocking): swap-outs and
         # prefetches launched in earlier iterations drained while compute
         # ran — their bookkeeping (page release, host installs, deferred
         # disk writes) lands here, off every lane's critical path
-        self.backend.poll_transfers()
+        with span("engine.poll"):
+            self.backend.poll_transfers()
         budget = self.token_budget
         plan: List[Tuple[Running, LaneWork]] = []
         # 1) running lanes ride every step: decode lanes cost no budget,
@@ -137,10 +145,17 @@ class NodeEngine:
 
         # 2) admission: pack waiting prompts into the remaining budget, with
         #    bounded lookahead past heads blocked by memory/fragmentation
-        budget = self._admit(plan, budget, now)
+        with span("engine.admit") as sa:
+            n_run = len(self.running)
+            budget = self._admit(plan, budget, now)
+            if recording():
+                sa.set_metadata(ids=" ".join(
+                    str(r.req.request_id) for r in self.running[n_run:]))
 
         if not plan:
             return 0.0
+        sp.set_metadata(lanes=len(plan),
+                        prompt_tokens=self.token_budget - budget)
 
         # context-aware lane ordering: widest context first, so the
         # backend's skew split (at most two sub-dispatches on the bucket
@@ -155,33 +170,34 @@ class NodeEngine:
             return 0.0
 
         # 4) advance every lane by what the step did
-        dt = res.duration
-        self.stats["stall_s"] += res.stall
-        any_decode = False
-        for r, ln in plan:
-            r.started = True
-            if ln.new_tokens:
-                self.stats["prefill_tokens"] += ln.new_tokens
-                self.stats["chunks"] += 1
-                r.prompt_left -= ln.new_tokens
-                r.consumed += ln.new_tokens
-                r.ctx_tokens += ln.new_tokens
-            if ln.is_decode:
-                any_decode = True
-            if ln.final:
-                r.ctx_tokens += 1
-                if r.req.first_token_at is None:
-                    r.req.first_token_at = now + dt
-                r.req.generated += 1
-                r.remaining -= 1
-                if r.remaining <= 0:
-                    r.req.finished_at = now + dt
-                    self.running.remove(r)
-                    self.completed.append(r.req)
-                    self.backend.finish(r.req, now + dt)
-        if any_decode:
-            self.stats["decode_steps"] += 1
-        self.stats["busy_s"] += dt
+        with span("engine.advance"):
+            dt = res.duration
+            self.stats["stall_s"] += res.stall
+            any_decode = False
+            for r, ln in plan:
+                r.started = True
+                if ln.new_tokens:
+                    self.stats["prefill_tokens"] += ln.new_tokens
+                    self.stats["chunks"] += 1
+                    r.prompt_left -= ln.new_tokens
+                    r.consumed += ln.new_tokens
+                    r.ctx_tokens += ln.new_tokens
+                if ln.is_decode:
+                    any_decode = True
+                if ln.final:
+                    r.ctx_tokens += 1
+                    if r.req.first_token_at is None:
+                        r.req.first_token_at = now + dt
+                    r.req.generated += 1
+                    r.remaining -= 1
+                    if r.remaining <= 0:
+                        r.req.finished_at = now + dt
+                        self.running.remove(r)
+                        self.completed.append(r.req)
+                        self.backend.finish(r.req, now + dt)
+            if any_decode:
+                self.stats["decode_steps"] += 1
+            self.stats["busy_s"] += dt
         return dt
 
     def _admit(self, plan: List[Tuple[Running, LaneWork]], budget: int,
@@ -199,7 +215,6 @@ class NodeEngine:
                 return False
             idx += 1
             skipped += 1
-            self.stats["admission_skips"] += 1
             return True
 
         while (idx < len(self.waiting)
@@ -266,6 +281,8 @@ class NodeEngine:
                         continue
                     break
             del self.waiting[idx]
+            if req.admitted_at is None:
+                req.admitted_at = time.perf_counter()
             if not self.reuses_kv and req.cached_tokens > 0:
                 self.stats["redundant_tokens"] += req.cached_tokens
             budget -= c

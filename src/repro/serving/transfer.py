@@ -39,8 +39,9 @@ Safety invariants:
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, List, Optional
+
+from repro.serving.tracing import span
 
 OUT, IN, PERSIST = "out", "in", "persist"
 
@@ -49,7 +50,7 @@ class Transfer:
     """One in-flight tier movement (all layers of one session direction)."""
 
     __slots__ = ("sid", "kind", "bufs", "on_complete", "on_release",
-                 "done", "poisoned", "launched_at", "nbytes")
+                 "done", "poisoned", "nbytes")
 
     def __init__(self, sid: str, kind: str, bufs,
                  on_complete: Optional[Callable[["Transfer"], None]] = None,
@@ -62,7 +63,6 @@ class Transfer:
         self.on_release = on_release     # poison path: free resources only
         self.done = False
         self.poisoned = False
-        self.launched_at = time.perf_counter()
         self.nbytes = nbytes
 
     def ready(self) -> bool:
@@ -113,7 +113,7 @@ class TransferEngine:
     def __init__(self):
         self.inflight: List[Transfer] = []
         self.stats = dict(launched=0, completed=0, poisoned=0,
-                          launched_bytes=0.0, fence_wait_s=0.0)
+                          launched_bytes=0.0)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -159,19 +159,19 @@ class TransferEngine:
         self._sweep()
 
     def fence(self, sid: Optional[str] = None,
-              kind: Optional[str] = None) -> float:
-        """Blocking fence of every matching in-flight transfer; returns the
-        wall seconds spent waiting (the *residual* cost the critical path
-        actually paid — ~0 when the transfer was launched early enough)."""
-        t0 = time.perf_counter()
-        for t in list(self.inflight):
-            if ((sid is None or t.sid == sid)
-                    and (kind is None or t.kind == kind)):
-                self._finish(t)
+              kind: Optional[str] = None) -> None:
+        """Blocking fence of every matching in-flight transfer.  The wait
+        (the *residual* cost the critical path actually paid — ~0 when the
+        transfer was launched early enough) is the ``transfer.fence`` span,
+        opened only when something is in flight to wait on."""
+        due = [t for t in self.inflight
+               if (sid is None or t.sid == sid)
+               and (kind is None or t.kind == kind)]
+        if due:
+            with span("transfer.fence", kind=kind or "any"):
+                for t in due:
+                    self._finish(t)
         self._sweep()
-        dt = time.perf_counter() - t0
-        self.stats["fence_wait_s"] += dt
-        return dt
 
     def drain(self) -> None:
         self.fence()
