@@ -924,12 +924,16 @@ def bench_quant(n_sessions: int = 10, kernel_mode: str = None):
     kq, ks = quantize_int8(kp, axis=(1, 2, 3))
     vq, vs = quantize_int8(vp, axis=(1, 2, 3))
     flags = jnp.asarray(rng.integers(0, 2, (P,)), jnp.int32)
-    quant = (kq, vq, ks, vs, flags)
+    # one layer's pools, passed stacked as layer 0
+    kp, vp = kp[None], vp[None]
+    quant = (kq[None], vq[None], ks, vs, flags)
     o_ref_q = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx,
-                                        mode="ref", quant=quant)
+                                        mode="ref", quant=quant, layer=0)
     o_int_q = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx,
-                                        mode="interpret", quant=quant)
-    o_fp = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx, mode="ref")
+                                        mode="interpret", quant=quant,
+                                        layer=0)
+    o_fp = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx, mode="ref",
+                                     layer=0)
     parity = dict(
         pallas_vs_oracle=float(jnp.max(jnp.abs(o_int_q - o_ref_q))),
         quant_vs_fp=float(jnp.max(jnp.abs(o_ref_q - o_fp))))

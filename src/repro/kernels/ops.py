@@ -21,6 +21,7 @@ from repro.kernels.paged_attention import paged_attention as _paged
 from repro.kernels.paged_attention import paged_chunk_attention as _chunk
 from repro.kernels.paged_attention import \
     paged_chunk_attention_quant as _chunk_quant
+from repro.kernels.paged_attention import paged_kv_write as _kv_write
 
 
 def _on_tpu() -> bool:
@@ -84,44 +85,82 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
 
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
                           ctx_lens, mode: str = "auto", bq=None,
-                          quant=None, mesh=None):
-    """Unified mixed-batch serving attention (decode = 1-token chunk).
+                          quant=None, mesh=None, *, layer):
+    """Unified mixed-batch serving attention (decode = 1-token chunk) over
+    layer ``layer`` of the stacked (L, P, Hkv, page, D) pools; a caller
+    holding one layer's pool passes ``pool[None]`` and layer 0.
     mode: auto | pallas | interpret | ref.  ``quant``: optional
     (kq_pages, vq_pages, k_scales, v_scales, page_quant) mixed-precision
-    shadow state — pages flagged quantized dequantize inside the kernel
-    (or oracle) instead of reading the fp pool.
+    shadow state — stacked int8 pools like the fp ones, the layer's own
+    (P,) scales and bits — pages flagged quantized dequantize inside the
+    kernel (or oracle) instead of reading the fp pool.
 
     ``mesh``: a mesh with a ``model`` axis over which q (heads) and the
-    pools (kv-heads, dim 1) are sharded.  A Pallas mode then runs the
-    kernel per shard under `jax.shard_map` (tables, offsets, context
-    lengths, scales and precision bits replicated); ``ref`` ignores it
-    and lets GSPMD partition the oracle."""
+    pools (kv-heads, dim 2) are sharded.  A Pallas mode then runs the
+    kernel per shard under `jax.shard_map` (the layer index, tables,
+    offsets, context lengths, scales and precision bits replicated);
+    ``ref`` ignores it and lets GSPMD partition the oracle."""
     if mode == "ref":
         return ref.paged_chunk_attention_ref(q, k_pages, v_pages,
                                              block_tables, q_offsets,
-                                             ctx_lens, quant=quant)
+                                             ctx_lens, layer=layer,
+                                             quant=quant)
     interpret = not _on_tpu() if mode == "auto" else (mode == "interpret")
     bq = _auto_tile(q.shape[1]) if bq is None else bq
 
-    def kernel(q, k_pages, v_pages, block_tables, q_offsets, ctx_lens,
-               quant):
+    def kernel(q, k_pages, v_pages, layer, block_tables, q_offsets,
+               ctx_lens, quant):
         if quant is not None:
             kq, vq, ks, vs, pq = quant
             return _chunk_quant(q, k_pages, v_pages, kq, vq, ks, vs, pq,
                                 block_tables, q_offsets, ctx_lens,
-                                bq=bq, interpret=interpret)
+                                layer=layer, bq=bq, interpret=interpret)
         return _chunk(q, k_pages, v_pages, block_tables, q_offsets,
-                      ctx_lens, bq=bq, interpret=interpret)
+                      ctx_lens, layer=layer, bq=bq, interpret=interpret)
 
     if mesh is not None:
         heads = P(None, None, "model", None)
-        pool = P(None, "model", None, None)
+        pool = P(None, None, "model", None, None)
         quant_spec = None if quant is None else (pool, pool, P(), P(), P())
         kernel = jax.shard_map(
             kernel, mesh=mesh, check_vma=False, out_specs=heads,
-            in_specs=(heads, pool, pool, P(), P(), P(), quant_spec))
-    return kernel(q, k_pages, v_pages, block_tables, q_offsets, ctx_lens,
-                  quant)
+            in_specs=(heads, pool, pool, P(), P(), P(), P(), quant_spec))
+    return kernel(q, k_pages, v_pages, layer, block_tables, q_offsets,
+                  ctx_lens, quant)
+
+
+def paged_kv_write(k_pages, v_pages, k, v, block_tables, q_offsets,
+                   ctx_lens, slot_pages, slot_offs, mode: str = "auto",
+                   mesh=None, *, layer):
+    """Write the step's new K/V rows (B, Sq, Hkv, D) into layer ``layer``
+    of the stacked pools; returns the pools.  A Pallas mode
+    read-modify-writes each page a lane's chunk touches, finding it
+    through the layer's block table at positions [q_offsets, ctx_lens);
+    ``ref`` scatters each row to the slot ``slot_pages``/``slot_offs``
+    give it.  The serving backend builds the slots from the same tables,
+    so both write the same rows.
+
+    ``mesh``: as for `paged_chunk_attention`; a Pallas mode runs per
+    kv-head shard under `jax.shard_map`."""
+    if mode == "ref":
+        return ref.paged_kv_write_ref(k_pages, v_pages, k, v, slot_pages,
+                                      slot_offs, layer=layer)
+    interpret = not _on_tpu() if mode == "auto" else (mode == "interpret")
+
+    def kernel(k_pages, v_pages, k, v, layer, block_tables, q_offsets,
+               ctx_lens):
+        return tuple(_kv_write(k_pages, v_pages, k, v, block_tables,
+                               q_offsets, ctx_lens, layer=layer,
+                               interpret=interpret))
+
+    if mesh is not None:
+        rows = P(None, None, "model", None)
+        pool = P(None, None, "model", None, None)
+        kernel = jax.shard_map(
+            kernel, mesh=mesh, check_vma=False, out_specs=(pool, pool),
+            in_specs=(pool, pool, rows, rows, P(), P(), P(), P()))
+    return kernel(k_pages, v_pages, k, v, layer, block_tables, q_offsets,
+                  ctx_lens)
 
 
 def flash_prefill(q, k, v, q_offset=0, mode: str = "auto",

@@ -1,8 +1,8 @@
 """Paged attention — Pallas TPU kernels with block-table indirection.
 
-Two kernels share the flash-decoding structure (page dimension innermost,
-running (m, l, acc) accumulators in VMEM scratch, scalar-prefetch block
-tables):
+Two attention kernels share the flash-decoding structure (page dimension
+innermost, running (m, l, acc) accumulators in VMEM scratch,
+scalar-prefetch block tables):
 
 * ``paged_attention`` — the single-token decode kernel (one query row per
   lane), kept as the minimal reference shape;
@@ -10,6 +10,10 @@ tables):
   step dispatches: every lane carries a CHUNK of queries at a per-lane
   ``q_offset`` (decode lanes are the one-token chunk), so one dispatch
   covers chunked prefill and batched decode together.
+
+``paged_kv_write`` is the serving step's KV write: it finds the pages a
+lane's chunk lands in through the same tables and read-modify-writes them
+in the pools it aliases.
 
 The page pool lives in HBM; the grid walks (batch, kv_head, page) with the
 page dimension innermost (sequential on a TPU core).  Block tables and
@@ -25,12 +29,16 @@ PagedAttention gather, reshaped for VMEM/MXU:
 
 Pages are the unit SYMPHONY migrates between tiers/nodes, so serving decode
 reads KV exactly in the layout the node manager stores it.  That layout is
-HEAD-MAJOR, ``(P, Hkv, page, D)`` per layer: one grid step's K/V block is
-``(None, None, page, D)``, whose last two dims are a whole (page, D) tile —
-Mosaic requires a block's last two dims to be (8, 128)-divisible or equal
-to the array's, which a token-major ``(P, page, Hkv, D)`` pool's (1, D)
-head slice is not.  Host payloads stay token-major ``(tokens, Hkv, D)``;
-`pages_to_tokens` is the one conversion.
+HEAD-MAJOR, ``(P, Hkv, page, D)`` per layer: one grid step's K/V block is a
+whole (page, D) tile — Mosaic requires a block's last two dims to be
+(8, 128)-divisible or equal to the array's, which a token-major
+``(P, page, Hkv, D)`` pool's (1, D) head slice is not.  The chunk kernels
+read the STACKED ``(L, P, Hkv, page, D)`` pool the serving step carries,
+with the layer index as their first scalar-prefetch operand: the step
+never slices a layer out of the pool (a copy of it), and a caller holding
+one layer passes ``pool[None]`` with layer 0.  Host payloads stay
+token-major ``(tokens, Hkv, D)``; `pages_to_tokens` is the one
+conversion.
 
 Dynamic-masking + DMA-elision contract (what shape-bucketed dispatch leans
 on): ctx_lens and block tables are traced data, never static shapes, so one
@@ -146,14 +154,14 @@ def _chunk_body(refs, *, bq: int, G: int, quant: bool):
     tile is materialised (fp tile vs int8 shadow tile dequantized
     in-register with its per-page scale)."""
     if quant:
-        (qoff_ref, ctx_ref, npages_ref, tables_ref,  # scalar prefetch
-         pq_ref, ks_ref, vs_ref,                     # (SMEM)
-         q_ref, k_ref, v_ref,                        # VMEM blocks
-         kq_ref, vq_ref,                             # int8 shadow tiles
-         o_ref,                                      # output block
-         m_ref, l_ref, acc_ref) = refs               # VMEM scratch
+        (_, qoff_ref, ctx_ref, npages_ref, tables_ref,  # scalar prefetch
+         pq_ref, ks_ref, vs_ref,                        # (SMEM)
+         q_ref, k_ref, v_ref,                           # VMEM blocks
+         kq_ref, vq_ref,                                # int8 shadow tiles
+         o_ref,                                         # output block
+         m_ref, l_ref, acc_ref) = refs                  # VMEM scratch
     else:
-        (qoff_ref, ctx_ref, npages_ref, tables_ref,
+        (_, qoff_ref, ctx_ref, npages_ref, tables_ref,
          q_ref, k_ref, v_ref,
          o_ref,
          m_ref, l_ref, acc_ref) = refs
@@ -234,7 +242,8 @@ def _chunk_kernel_quant(*refs, bq: int, G: int):
 
 
 def _chunk_kv_index(bq: int, page: int):
-    """Clamped K/V index map for the chunk grid (b, h, qi, p).
+    """Clamped K/V index map for the chunk grid (b, h, qi, p) over the
+    stacked (L, P, Hkv, page, D) pools: block (layer, page id, h).
 
     Pages past the lane's own page count OR past this q block's causal
     horizon re-map to the lane's last relevant page, so consecutive grid
@@ -242,28 +251,35 @@ def _chunk_kv_index(bq: int, page: int):
     ``npg`` (per-lane page counts = ceil(ctx / page)) rides scalar-prefetch
     SMEM; the horizon is derived from the prefetched q_offsets.  Clamping
     never changes a RELEVANT step's fetch: valid > 0  ⟺  p < rel."""
-    def kv_index(b, h, qi, p, qo, ctx, npg, tab, *_):
+    def kv_index(b, h, qi, p, lyr, qo, ctx, npg, tab, *_):
         horizon = (qo[b] + (qi + 1) * bq - 1) // page + 1
         rel = jnp.minimum(npg[b], horizon)
         p_eff = jnp.minimum(p, jnp.maximum(rel - 1, 0))
-        return (tab[b, p_eff], h, 0, 0)
+        return (lyr[0], tab[b, p_eff], h, 0, 0)
     return kv_index
+
+
+def _layer_operand(layer):
+    """The layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
                                 k_scales, v_scales, page_quant,
                                 block_tables, q_offsets, ctx_lens, *,
-                                bq: int = 128, interpret: bool = True):
+                                layer, bq: int = 128,
+                                interpret: bool = True):
     """`paged_chunk_attention` over mixed-precision pools: pages whose
     ``page_quant`` bit is set are read from the int8 shadow pool and
     dequantized in the kernel body with their per-page fp32 scale; the
-    rest read the fp pool.  kq/vq_pages: (P, Hkv, page, D) int8;
-    k/v_scales, page_quant: (P,).  Same grid/masking/DMA-elision contract
-    as the all-fp kernel (the fp and int8 tiles of a page share one
-    clamped index map, so both copies are elided together)."""
+    rest read the fp pool.  kq/vq_pages: stacked (L, P, Hkv, page, D) int8,
+    read at ``layer`` like the fp pools; k/v_scales, page_quant: (P,), the
+    layer's own.  Same grid/masking/DMA-elision contract as the all-fp
+    kernel (the fp and int8 tiles of a page share one clamped index map,
+    so both copies are elided together)."""
     B, Sq, H, D = q.shape
-    P, Hkv, page, _ = k_pages.shape
+    _, _, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     bq = min(bq, Sq)
@@ -273,10 +289,10 @@ def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
 
     grid = (B, Hkv, Sq // bq, maxp)
     kern = functools.partial(_chunk_kernel_quant, bq=bq, G=G)
-    kv_spec = pl.BlockSpec((None, None, page, D),
+    kv_spec = pl.BlockSpec((None, None, None, page, D),
                            _chunk_kv_index(bq, page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=8,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, G, D),
@@ -296,7 +312,7 @@ def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq, G, D), q.dtype),
         interpret=interpret,
         **_dim_semantics(interpret, 3),
-    )(q_offsets, ctx_lens, npages, block_tables,
+    )(_layer_operand(layer), q_offsets, ctx_lens, npages, block_tables,
       page_quant.astype(jnp.int32), k_scales.astype(jnp.float32),
       v_scales.astype(jnp.float32), q5, k_pages, v_pages,
       kq_pages, vq_pages)
@@ -305,18 +321,19 @@ def paged_chunk_attention_quant(q, k_pages, v_pages, kq_pages, vq_pages,
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
-                          ctx_lens, *, bq: int = 128,
+                          ctx_lens, *, layer, bq: int = 128,
                           interpret: bool = True):
     """Mixed-batch paged attention: every lane carries a chunk of queries.
 
-    q: (B, Sq, H, D); k/v_pages: (P, Hkv, page, D); block_tables: (B, maxp);
-    q_offsets/ctx_lens: (B,) int32.  Lane b's query token i sits at absolute
-    position q_offsets[b] + i and attends KV positions <= it (causal) that
-    are < ctx_lens[b].  Decode is the Sq = 1 special case (q_offset =
-    ctx - 1); chunked prefill sets q_offset = n_cached.  ctx_len = 0 masks a
-    padded lane entirely (finishes as zeros, no KV read); padded query rows
-    of a live lane (i >= its chunk length) produce garbage the caller never
-    reads.  Returns (B, Sq, H, D).
+    q: (B, Sq, H, D); k/v_pages: stacked (L, P, Hkv, page, D), of which
+    layer ``layer`` (a traced int32 scalar) is read; block_tables:
+    (B, maxp), that layer's; q_offsets/ctx_lens: (B,) int32.  Lane b's
+    query token i sits at absolute position q_offsets[b] + i and attends
+    KV positions <= it (causal) that are < ctx_lens[b].  Decode is the
+    Sq = 1 special case (q_offset = ctx - 1); chunked prefill sets q_offset
+    = n_cached.  ctx_len = 0 masks a padded lane entirely (finishes as
+    zeros, no KV read); padded query rows of a live lane (i >= its chunk
+    length) produce garbage the caller never reads.  Returns (B, Sq, H, D).
 
     Grid: (B, Hkv, q_blocks, pages), page innermost with running (m, l, acc)
     flash accumulators in VMEM scratch; q_offsets/ctx_lens/tables are traced
@@ -327,7 +344,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
     bandwidth proportional to its OWN pages, not the bucket width — see the
     module docstring for the table-padding invariant this leans on."""
     B, Sq, H, D = q.shape
-    P, Hkv, page, _ = k_pages.shape
+    _, _, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     bq = min(bq, Sq)
@@ -337,10 +354,10 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
 
     grid = (B, Hkv, Sq // bq, maxp)
     kern = functools.partial(_chunk_kernel, bq=bq, G=G)
-    kv_spec = pl.BlockSpec((None, None, page, D),
+    kv_spec = pl.BlockSpec((None, None, None, page, D),
                            _chunk_kv_index(bq, page))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, G, D),
@@ -360,7 +377,8 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, q_offsets,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq, G, D), q.dtype),
         interpret=interpret,
         **_dim_semantics(interpret, 3),
-    )(q_offsets, ctx_lens, npages, block_tables, q5, k_pages, v_pages)
+    )(_layer_operand(layer), q_offsets, ctx_lens, npages, block_tables, q5,
+      k_pages, v_pages)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Sq, H, D)
 
 
@@ -407,3 +425,89 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         **_dim_semantics(interpret, 2),
     )(ctx_lens, npages, block_tables, q4, k_pages, v_pages)
     return out.reshape(B, H, D)
+
+
+def _kv_tiles(Sq: int, page: int) -> int:
+    """Pages a chunk of Sq consecutive tokens can touch, at any offset."""
+    return (Sq + 2 * page - 2) // page
+
+
+def _shift_rows(x, q_offsets, page: int, R: int):
+    """(B, Sq, Hkv, D) new rows -> (B, Hkv, R, D) with lane b's token i at
+    row q_offsets[b] % page + i, so row r of the lane's tile j is row
+    j * page + r.  One window per lane (a vmapped dynamic slice), not one
+    per row."""
+    Sq = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (page - 1, R - Sq), (0, 0), (0, 0)))
+    start = page - 1 - q_offsets % page
+    rows = jax.vmap(lambda a, s: jax.lax.dynamic_slice_in_dim(a, s, R))(
+        xp, start)
+    return rows.transpose(0, 2, 1, 3)
+
+
+def _kv_write_kernel(lyr_ref, qoff_ref, ctx_ref, tables_ref,  # SMEM
+                     k_ref, v_ref, kp_ref, vp_ref,  # new rows, old tiles
+                     ko_ref, vo_ref):               # the tiles written
+    b, j = pl.program_id(0), pl.program_id(1)
+    page = kp_ref.shape[1]
+    lo = qoff_ref[b] % page
+    hi = lo + ctx_ref[b] - qoff_ref[b]
+    row = j * page + jax.lax.broadcasted_iota(jnp.int32, kp_ref.shape, 1)
+    new = (row >= lo) & (row < hi)
+    ko_ref[...] = jnp.where(new, k_ref[...], kp_ref[...])
+    vo_ref[...] = jnp.where(new, v_ref[...], vp_ref[...])
+
+
+def _kv_tile_index(page: int, T: int, trash: int):
+    """Pool block of grid step (b, j): all kv heads of table column
+    q_offsets[b] // page + j at the layer, or the trash page when tile j
+    holds none of the chunk (past its end, or a padded lane's ctx 0)."""
+    def index(b, j, lyr, qo, ctx, tab):
+        live = j * page < qo[b] % page + ctx[b] - qo[b]
+        col = jnp.minimum(qo[b] // page + j, T - 1)
+        return (lyr[0], jnp.where(live, tab[b, col], trash), 0, 0, 0)
+    return index
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write(k_pages, v_pages, k, v, block_tables, q_offsets,
+                   ctx_lens, *, layer, interpret: bool = True):
+    """Write a step's new K/V rows into layer ``layer`` of the stacked
+    (L, P+1, Hkv, page, D) pools in place; returns the pools.
+
+    k/v: (B, Sq, Hkv, D); lane b's first ctx_lens[b] - q_offsets[b] rows
+    are its tokens at positions [q_offsets[b], ctx_lens[b]), which sit in
+    rows pos % page of pages block_tables[b, pos // page] (the layer's
+    (B, T) table) — the slots the serving backend assigns.  The rest are
+    padding and are not written.  Page P (the last) is the trash page.
+
+    One grid step per (lane, page of its chunk) read-modify-writes that
+    page's (Hkv, page, D) block: a scatter would pay per (token, head)
+    row.  The pools alias the outputs, so blocks no step visits keep
+    their contents and nothing of the pools is copied.  Tiles with no
+    token of the chunk aim at the trash page, so no live page is ever
+    visited twice."""
+    B, Sq, Hkv, D = k.shape
+    _, n_slots, _, page, _ = k_pages.shape
+    J = _kv_tiles(Sq, page)
+    rows = [_shift_rows(x.astype(k_pages.dtype), q_offsets, page, J * page)
+            for x in (k, v)]
+    pool_spec = pl.BlockSpec(
+        (None, None, Hkv, page, D),
+        _kv_tile_index(page, block_tables.shape[1], n_slots - 1))
+    row_spec = pl.BlockSpec((None, Hkv, page, D),
+                            lambda b, j, *_: (b, 0, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, J),
+        in_specs=[row_spec, row_spec, pool_spec, pool_spec],
+        out_specs=[pool_spec, pool_spec],
+    )
+    return pl.pallas_call(
+        _kv_write_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        input_output_aliases={6: 0, 7: 1},
+        interpret=interpret,
+    )(_layer_operand(layer), q_offsets, ctx_lens, block_tables, *rows,
+      k_pages, v_pages)

@@ -44,19 +44,36 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens):
     return o.reshape(B, H, D).astype(q.dtype)
 
 
+def paged_kv_write_ref(k_pages, v_pages, k, v, slot_pages, slot_offs, *,
+                       layer):
+    """The step's KV write as a plain scatter: row (b, i) of k/v (B, Sq,
+    Hkv, D) lands at [layer, slot_pages[b, i], head, slot_offs[b, i]] of
+    the stacked (L, P+1, Hkv, page, D) pools, one row per (token, kv
+    head); padded rows' slots aim at the trash page.  Returns the pools.
+    The window is D alone, the pools' minor dim, so XLA keeps their
+    layout (an (Hkv, D) window makes it relayout the whole pool around
+    the write)."""
+    heads = jnp.arange(k.shape[2])
+    at = (layer, slot_pages[..., None], heads, slot_offs[..., None])
+    return (k_pages.at[at].set(k.astype(k_pages.dtype)),
+            v_pages.at[at].set(v.astype(v_pages.dtype)))
+
+
 def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables,
-                              q_offsets, ctx_lens, quant=None):
+                              q_offsets, ctx_lens, *, layer, quant=None):
     """Mixed-batch paged attention: each lane is a chunk of queries.
 
     q:            (B, Sq, H, D) — lane b's token i at position q_offsets[b]+i
-    k/v_pages:    (P, Hkv, page_size, D)
-    block_tables: (B, max_pages) int32
+    k/v_pages:    (L, P, Hkv, page_size, D) stacked pools
+    layer:        int32 scalar — the layer of the pools that is read
+    block_tables: (B, max_pages) int32, that layer's
     q_offsets:    (B,) int32 — cached context before the chunk
     ctx_lens:     (B,) int32 — total valid KV incl. the chunk (0 = padded
                   lane, output zeroed to match the kernel's page skip)
     quant:        optional (kq_pages, vq_pages, k_scales, v_scales,
-                  page_quant) — int8 shadow pools (P, Hkv, page, D),
-                  per-page fp32 scales (P,), and the per-page precision
+                  page_quant) — stacked int8 shadow pools
+                  (L, P, Hkv, page, D) read at ``layer``, the layer's
+                  per-page fp32 scales (P,), and its per-page precision
                   bit (P,) int32; pages flagged quantized are dequantized
                   from the shadow pool to the pool dtype, the rest read
                   full precision
@@ -71,20 +88,20 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables,
     duplicated rows sit at kpos >= ctx and never survive the mask.
     """
     B, Sq, H, D = q.shape
-    P, Hkv, page, _ = k_pages.shape
+    _, _, Hkv, page, _ = k_pages.shape
     G = H // Hkv
     maxp = block_tables.shape[1]
     S = maxp * page
 
-    k = k_pages[block_tables]
-    v = v_pages[block_tables]
+    k = k_pages[layer, block_tables]
+    v = v_pages[layer, block_tables]
     if quant is not None:
         kq_pages, vq_pages, k_scales, v_scales, page_quant = quant
         isq = (page_quant[block_tables] > 0)[..., None, None, None]
-        kd = (kq_pages[block_tables].astype(jnp.float32)
+        kd = (kq_pages[layer, block_tables].astype(jnp.float32)
               * k_scales[block_tables][..., None, None, None]
               ).astype(k.dtype)
-        vd = (vq_pages[block_tables].astype(jnp.float32)
+        vd = (vq_pages[layer, block_tables].astype(jnp.float32)
               * v_scales[block_tables][..., None, None, None]
               ).astype(v.dtype)
         k = jnp.where(isq, kd, k)

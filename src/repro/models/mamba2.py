@@ -519,8 +519,9 @@ class Zamba2LM:
         k = L.apply_rope(k, positions, c.rope_theta)
         kp = kp.at[slot_pages, :, slot_offs].set(k.astype(kp.dtype))
         vp = vp.at[slot_pages, :, slot_offs].set(v.astype(vp.dtype))
-        o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets, ctx_lens,
-                                      mode=kernel_mode)
+        o = ops.paged_chunk_attention(q, kp[None], vp[None], table,
+                                      q_offsets, ctx_lens, mode=kernel_mode,
+                                      layer=0)
         x = x + (o.reshape(B, S, -1) @ w["wo"])
         h = L.swiglu(L.rms_norm(x, w["ln2"], c.norm_eps), w["w1"], w["w3"],
                      w["w2"])

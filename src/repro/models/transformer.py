@@ -248,14 +248,22 @@ class DenseLM:
     # step's slice of new prompt tokens — so one engine iteration is one
     # fused dispatch regardless of its prefill/decode composition.  The
     # layer stack is a `jax.lax.scan` over the already-stacked block weights
-    # with the KV scatter, the unified paged_chunk_attention kernel, and the
-    # FFN inside the scanned body.
+    # and the layer index, with the KV write, the unified
+    # paged_chunk_attention kernel, and the FFN inside the scanned body.
+    #
+    # The two stacked pools ride the scan CARRY, not its xs/ys: a Pallas
+    # write kernel aliased to the pools read-modify-writes the pages of
+    # layer l that the step's tokens land in, and the attention kernel
+    # reads layer l of the stacked pool through its index map.  With the
+    # pools donated at the jit boundary, the compiled step keeps them in
+    # one buffer each, in their own layout, and never slices, stacks or
+    # relayouts them — each of those would be a pass over the whole pool.
     #
     # Every data-dependent quantity (q_offsets, ctx_lens, last_idx) is
     # traced, so the jit cache is keyed only on the SHAPE BUCKET (padded
     # lanes x padded tokens-per-step x table width) the backend dispatches
     # into — steady-state serving is recompile-free.  Padded token slots
-    # scatter their KV into a caller-supplied trash slot and their outputs
+    # write KV to no live page (at most the trash page) and their outputs
     # are never read (attention rows are independent, the FFN is
     # position-wise, and logits/argmax are taken at `last_idx` only); a
     # padded lane sets ctx_len = 0 and is masked out of attention entirely.
@@ -272,16 +280,17 @@ class DenseLM:
         B, Sq = ids.shape
         positions = q_offsets[:, None] + jnp.arange(Sq)[None, :]
 
-        def body(x, xs):
+        def body(carry, xs):
+            x, kp, vp = carry
             if quant is None:
-                w, kp, vp, table, sp, so = xs
+                w, layer, table, sp, so = xs
                 qt = None
             else:
-                # per-layer slices of the int8 shadow pools, scales and the
-                # precision bits ride the scan as read-only xs — only
-                # compress_paged ever writes them
-                w, kp, vp, table, sp, so, kq, vq, ks, vs, pq = xs
-                qt = (kq, vq, ks, vs, pq)
+                # the int8 shadow pools are read whole at `layer`, like the
+                # fp pools; the layer's scales and precision bits ride the
+                # scan as xs — only compress_paged ever writes them
+                w, layer, table, sp, so, ks, vs, pq = xs
+                qt = (quant[0], quant[1], ks, vs, pq)
             with jax.named_scope("attn"):
                 h = L.rms_norm(x, w["ln1"], c.norm_eps)
                 q = (h @ w["wq"]).reshape(B, Sq, c.n_heads, c.d_head)
@@ -293,27 +302,33 @@ class DenseLM:
                 q = L.apply_rope(q, positions, c.rope_theta)
                 k = L.apply_rope(k, positions, c.rope_theta)
             with jax.named_scope("kv_write"):
-                kp = kp.at[sp, :, so].set(k.astype(kp.dtype))
-                vp = vp.at[sp, :, so].set(v.astype(vp.dtype))
+                # whole pages in place: an (Hkv, D)-window scatter relayouts
+                # the carried pool, a D-window one pays per (token, head) row
+                kp, vp = ops.paged_kv_write(kp, vp, k, v, table, q_offsets,
+                                            ctx_lens, sp, so,
+                                            mode=kernel_mode, mesh=mesh,
+                                            layer=layer)
             with jax.named_scope("attn"):
                 o = ops.paged_chunk_attention(q, kp, vp, table, q_offsets,
                                               ctx_lens, mode=kernel_mode,
-                                              quant=qt, mesh=mesh)
+                                              quant=qt, mesh=mesh,
+                                              layer=layer)
                 x = x + o.reshape(B, Sq, -1) @ w["wo"]
             with jax.named_scope("ffn"):
                 h2 = L.rms_norm(x, w["ln2"], c.norm_eps)
                 x = x + L.swiglu(h2, w["w1"], w["w3"], w["w2"])
-            return x, (kp, vp)
+            return (x, kp, vp), None
 
-        xs = (params["blocks"], k_pool, v_pool, tables,
+        xs = (params["blocks"], jnp.arange(c.n_layers), tables,
               slot_pages, slot_offs)
         if quant is not None:
-            xs = xs + tuple(quant)
+            xs = xs + tuple(quant[2:])
         # device scopes: ops under `layers` in none of the body's scopes
-        # are the scan's own slicing of its inputs and stacking of its
-        # outputs, the KV pools above all
+        # are the scan's own loop and its per-layer slicing of the weights,
+        # tables and slots; the pools are carried whole
         with jax.named_scope("layers"):
-            x, (k_pool, v_pool) = jax.lax.scan(body, x, xs)
+            (x, k_pool, v_pool), _ = jax.lax.scan(
+                body, (x, k_pool, v_pool), xs)
         with jax.named_scope("head"):
             x = L.rms_norm(x, params["ln_f"], c.norm_eps)
             logits = self._unembed(params, x[jnp.arange(B), last_idx])
@@ -356,7 +371,10 @@ class DenseLM:
         last_idx: (B,) traced int32 — index of the lane's last real token,
           where logits/argmax are read (0 for padded lanes).
         slot_pages/slot_offs: (L, B, Sq) destination of each token's KV;
-          padded slots must point at a trash slot.
+          padded slots must point at a trash slot.  A real token's slot
+          must be its position's: (tables[l, b, pos // page], pos % page),
+          as the backend builds them — the Pallas write kernel finds the
+          pages through the tables, the ``ref`` mode writes to the slots.
         quant: optional mixed-precision shadow state — (kq_pool, vq_pool,
           k_scale (L, P), v_scale (L, P), page_quant (L, P) int32); pages
           whose bit is set dequantize from the int8 pool inside the

@@ -73,11 +73,14 @@ def test_paged_attention_page_permutation_invariance():
                                rtol=1e-6, atol=1e-6)
 
 
-def _mk_chunk(rng, B, Sq, H, Hkv, D, page, maxp, dtype, decode=False):
+def _mk_chunk(rng, B, Sq, H, Hkv, D, page, maxp, dtype, decode=False,
+              L=1):
+    """Stacked (L, P, Hkv, page, D) pools; the chunk kernels read one
+    layer of them."""
     P = maxp * B + 2
     q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, Hkv, page, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(L, P, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(L, P, Hkv, page, D)), dtype)
     tables = jnp.asarray(
         rng.permutation(P)[:B * maxp].reshape(B, maxp), jnp.int32)
     if decode:                                  # q_len 1: qoff = ctx - 1
@@ -103,8 +106,8 @@ def test_paged_chunk_attention_sweep(B, Sq, H, Hkv, D, page, maxp, dtype):
     garbage by contract)."""
     rng = np.random.default_rng(hash((B, Sq, H)) % 2**32)
     args = _mk_chunk(rng, B, Sq, H, Hkv, D, page, maxp, dtype)
-    out = paged_chunk_attention(*args, bq=4, interpret=True)
-    want = ref.paged_chunk_attention_ref(*args)
+    out = paged_chunk_attention(*args, layer=0, bq=4, interpret=True)
+    want = ref.paged_chunk_attention_ref(*args, layer=0)
     tol = TOL if dtype == jnp.bfloat16 else TOL32
     qoff, ctx = np.asarray(args[4]), np.asarray(args[5])
     for b in range(B):
@@ -120,9 +123,9 @@ def test_paged_chunk_attention_decode_is_special_case():
     rng = np.random.default_rng(23)
     q, kp, vp, tables, qoff, ctx = _mk_chunk(
         rng, 3, 1, 8, 4, 32, 8, 4, jnp.float32, decode=True)
-    out = paged_chunk_attention(q, kp, vp, tables, qoff, ctx, bq=1,
-                                interpret=True)
-    want = ref.paged_attention_ref(q[:, 0], kp, vp, tables, ctx)
+    out = paged_chunk_attention(q, kp, vp, tables, qoff, ctx, layer=0,
+                                bq=1, interpret=True)
+    want = ref.paged_attention_ref(q[:, 0], kp[0], vp[0], tables, ctx)
     np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(want),
                                **TOL32)
 
@@ -133,10 +136,10 @@ def test_paged_chunk_attention_padded_lane_is_inert():
     rng = np.random.default_rng(29)
     q, kp, vp, tables, qoff, ctx = _mk_chunk(
         rng, 3, 8, 4, 2, 32, 8, 4, jnp.float32)
-    full = paged_chunk_attention(q, kp, vp, tables, qoff, ctx,
+    full = paged_chunk_attention(q, kp, vp, tables, qoff, ctx, layer=0,
                                  bq=4, interpret=True)
     ctx_pad = ctx.at[1].set(0)
-    out = paged_chunk_attention(q, kp, vp, tables, qoff, ctx_pad,
+    out = paged_chunk_attention(q, kp, vp, tables, qoff, ctx_pad, layer=0,
                                 bq=4, interpret=True)
     np.testing.assert_allclose(np.asarray(out[1]), 0.0)
     for b in (0, 2):

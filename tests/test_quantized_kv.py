@@ -129,15 +129,15 @@ def _kernel_case(kind: str, seed: int = 0):
     H, D, P, maxp, B, Sq = 4, 16, 6, 2, 2, 8
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (P, Hkv, PAGE, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (P, Hkv, PAGE, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (1, P, Hkv, PAGE, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (1, P, Hkv, PAGE, D), jnp.float32)
     tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     q_off = jnp.asarray([3, 8], jnp.int32)
     ctx = q_off + Sq
-    kq, ksc = quantize_int8(k_pages, axis=(1, 2, 3))
-    vq, vsc = quantize_int8(v_pages, axis=(1, 2, 3))
+    kq, ksc = quantize_int8(k_pages[0], axis=(1, 2, 3))
+    vq, vsc = quantize_int8(v_pages[0], axis=(1, 2, 3))
     flags = jnp.zeros((P,), jnp.int32).at[jnp.asarray([0, 3])].set(1)
-    quant = (kq, vq, ksc, vsc, flags)
+    quant = (kq[None], vq[None], ksc, vsc, flags)
     return q, k_pages, v_pages, tables, q_off, ctx, quant
 
 
@@ -145,9 +145,11 @@ def _kernel_case(kind: str, seed: int = 0):
 def test_kernel_quant_parity(kind):
     q, kp, vp, tab, qo, ctx, quant = _kernel_case(kind)
     args = (q, kp, vp, tab, qo, ctx)
-    o_ref_q = ops.paged_chunk_attention(*args, mode="ref", quant=quant)
-    o_int_q = ops.paged_chunk_attention(*args, mode="interpret", quant=quant)
-    o_ref = ops.paged_chunk_attention(*args, mode="ref")
+    o_ref_q = ops.paged_chunk_attention(*args, mode="ref", quant=quant,
+                                        layer=0)
+    o_int_q = ops.paged_chunk_attention(*args, mode="interpret", quant=quant,
+                                        layer=0)
+    o_ref = ops.paged_chunk_attention(*args, mode="ref", layer=0)
     # Pallas quant kernel against the jnp quant oracle: same math, near-exact
     assert float(jnp.max(jnp.abs(o_int_q - o_ref_q))) < 1e-5
     # quant vs fp: lossy but bounded, and actually lossy (flags were applied)
@@ -163,8 +165,9 @@ def test_kernel_all_fp_flags_bit_exact(kind):
     off = (kq, vq, ks, vs, jnp.zeros_like(_))
     for mode in ("ref", "interpret"):
         o_q = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx,
-                                        mode=mode, quant=off)
-        o = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx, mode=mode)
+                                        mode=mode, quant=off, layer=0)
+        o = ops.paged_chunk_attention(q, kp, vp, tab, qo, ctx, mode=mode,
+                                      layer=0)
         assert float(jnp.max(jnp.abs(o_q - o))) == 0.0, mode
 
 
