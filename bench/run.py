@@ -5,9 +5,11 @@
 
 The cell, its configuration and its traffic mix are found by name from
 ``BENCHMARK.json``: ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, and one reader per per-layer metric in
-``bench/metrics/<metric>.py``.  A cell, mix or metric is added by adding
-a file and an entry; this script needs no edit.
+``bench/traffic/<traffic>.json``, the architecture module the
+configuration's ``"reference"`` names, ``bench/arch/<reference>.py``, and
+one reader per per-layer metric in ``bench/metrics/<metric>.py``.  A
+cell, mix, architecture or metric is added by adding a file and an entry;
+this script needs no edit.
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read from a profiler trace of
@@ -22,7 +24,6 @@ fewer chips than the cell asks for: it never falls back to the CPU.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import shutil
@@ -36,11 +37,8 @@ BENCH = ROOT / "bench"
 
 
 def load_reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench.model import load_file
+    return load_file(BENCH / "metrics" / f"{name}.py").read
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -178,6 +176,13 @@ def main(argv=None) -> int:
         return 2
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(ROOT))
+    from bench.model import arch_path, load_config
+    conf = load_config(cell["config"])
+    if not arch_path(conf).is_file():
+        print(f"run.py: configuration {cell['config']!r} names the "
+              f"reference {conf['reference']!r}, and there is no "
+              f"{arch_path(conf)}", file=sys.stderr)
+        return 2
     import jax
     cache = use_compile_cache()
     devs = jax.devices()
@@ -193,14 +198,13 @@ def main(argv=None) -> int:
               f"bench/peaks.json", file=sys.stderr)
         return 2
 
-    from bench.model import load_config
     from bench.traffic import load_mix
     print(f"run.py: {args.workload} seed {args.seed} on {kind} "
           f"x{len(devs)}; compile cache {cache}", file=sys.stderr,
           flush=True)
-    out = measure(spec, cell, load_config(cell["config"]),
-                  load_mix(cell["traffic"]), args.seed, args.seconds,
-                  bool(args.trace), peaks[kind], devs, t_start=t_start)
+    out = measure(spec, cell, conf, load_mix(cell["traffic"]), args.seed,
+                  args.seconds, bool(args.trace), peaks[kind], devs,
+                  t_start=t_start)
     print(json.dumps(out), flush=True)
     return 0
 

@@ -21,8 +21,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import flops, reference, tails
-from bench.model import make_params, model_config
+from bench import tails
+from bench.model import load_arch, make_params
 from bench.traffic import Traffic, generate
 
 
@@ -79,15 +79,16 @@ class Cell:
 
         self.conf, self.mix, self.seed = conf, mix, seed
         srv = conf["serving"]
-        self.cfg = cfg = model_config(conf)
+        self.arch = arch = load_arch(conf)
+        self.cfg = cfg = arch.model_config(conf)
         self.page = srv["page_size"]
         t0 = time.perf_counter()
-        self.params = make_params(cfg, seed)
+        self.params = make_params(arch.param_shapes(cfg), cfg.dtype, seed)
         jax.block_until_ready(self.params)
         self.params_s = time.perf_counter() - t0
         model = get_model(cfg) if model is None else model
         hbm_pages = srv["hbm_pages"]
-        budget = hbm_pages * self.page * self._token_bytes()
+        budget = hbm_pages * self.page * arch.kv_token_bytes(cfg)
         # the store's HBM budget is the pool's: CostModel reserves 10%
         # and the weights, so give it the chip size that leaves exactly
         # the backend's fp budget
@@ -109,11 +110,6 @@ class Cell:
         self.traffic: Traffic = generate(
             mix, seed, hbm_pages * self.page, seconds, cfg.vocab)
         self.ids: Dict[str, List[int]] = {}
-
-    def _token_bytes(self) -> int:
-        c = self.cfg
-        itemsize = 2 if c.dtype in ("bfloat16", "float16") else 4
-        return c.n_layers * 2 * c.n_kv_heads * c.d_head * itemsize
 
     # -- set-up ----------------------------------------------------------
 
@@ -194,6 +190,7 @@ class Cell:
         from repro.core.advisory import AdvisoryRequest, InferenceRequest
         span = annotate or _null_span
         eng, be, mgr, cfg = self.eng, self.be, self.mgr, self.cfg
+        arch = self.arch
         turns = self.traffic.turns
         advs = sorted(range(len(turns)), key=lambda i: turns[i].advise_at)
         w = Window(seconds=seconds)
@@ -304,10 +301,10 @@ class Cell:
                     q = deferred.get(rec.sid)
                     if q:
                         submit(q.pop(0), t)
-            w.step_flops += flops.step_flops(cfg, lanes)
+            w.step_flops += arch.step_flops(cfg, lanes)
             if lanes:
                 w.attn_steps.append(
-                    flops.step_attn(cfg, lanes, self.page, kv_bytes))
+                    arch.step_attn(cfg, lanes, self.page, kv_bytes))
         # turns due before the close whose session was still busy: they
         # arrived, so they count in the tail at their wait so far
         for i in (i for q in deferred.values() for i in q):
@@ -366,15 +363,17 @@ def sample(records: List[Record], seed: int, tokens: int,
 def gaps(conf: dict, params, rec: Record, fp8: bool = False) -> np.ndarray:
     """Per served token: how far below the reference's best logit the
     token lies, in standard deviations of the reference's logits at that
-    position.  With ``fp8`` the token is the one the fp8 control puts
-    first, read against the float32 reference."""
+    position.  The reference is the architecture module's (`load_arch`).
+    With ``fp8`` the token is the one the fp8 control puts first, read
+    against the float32 reference."""
     out = list(rec.req.output_ids)
     seq = rec.prefix + out[:-1]
     at = np.arange(len(rec.prefix) - 1, len(seq))
-    ref = reference.logits(conf, params, seq, at)
+    arch = load_arch(conf)
+    ref = arch.logits(conf, params, seq, at)
     toks = np.asarray(out)
     if fp8:
-        toks = reference.logits(conf, params, seq, at, fp8=True).argmax(-1)
+        toks = arch.logits(conf, params, seq, at, fp8=True).argmax(-1)
     best = ref.max(-1)
     return (best - ref[np.arange(len(at)), toks]) / ref.std(-1)
 

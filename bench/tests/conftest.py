@@ -1,9 +1,10 @@
 """Shared small sizes for the benchmark's CPU tests.
 
-`tiny` shrinks a real configuration file to widths the CPU runs in
-seconds; `tiny_mix` is a short mix on the same generator.  The chip entry
-(`bench/run.py main`) is never called here: the tests drive
-`run.measure` and `serve.run` directly, with CPU devices."""
+`tiny` is every configuration in ``BENCHMARK.json`` in turn, shrunk to
+widths the CPU runs in seconds; `tiny_mix` is a short mix on the same
+generator.  The chip entry (`bench/run.py main`) is never run to a
+result here: the tests drive `run.measure` and `serve.run` directly,
+with CPU devices."""
 import copy
 import json
 import sys
@@ -15,22 +16,34 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from bench.model import load_arch, load_config  # noqa: E402
+
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+
 
 def shrink(conf: dict) -> dict:
+    """Small sizes for the keys every configuration shares; the
+    architecture module's own `shrink`, where it has one, sets the keys
+    only it has."""
     c = copy.deepcopy(conf)
     c.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
              num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
              vocab_size=256)
     c["serving"].update(page_size=8, hbm_pages=128, n_pages=128, max_batch=2,
                         token_budget=16)
-    return c
+    arch = load_arch(c)
+    return arch.shrink(c) if hasattr(arch, "shrink") else c
 
 
 @pytest.fixture
-def tiny():
-    conf = json.loads((ROOT / "bench" / "configs"
-                       / "yi-6b-8l.json").read_text())
-    return shrink(conf)
+def shrink_conf():
+    """`shrink`, for a test that shrinks a configuration of its own."""
+    return shrink
+
+
+@pytest.fixture(params=[c["name"] for c in SPEC["configs"]])
+def tiny(request):
+    return shrink(load_config(request.param))
 
 
 @pytest.fixture

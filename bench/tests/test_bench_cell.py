@@ -10,14 +10,22 @@ import pytest
 from bench import run, serve
 
 SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-# the benchmark's cell, run on the small configuration of the fixtures
-CELL = SPEC["workloads"][0]
 PEAK = dict(bf16_flops=1e12, hbm_bytes_per_s=1e11)
 
 
+def _cell(conf):
+    """The first benchmark cell of a configuration, run here on its small
+    sizes."""
+    return next(w for w in SPEC["workloads"] if w["config"] == conf["name"])
+
+
 def _measure(conf, mix, seed, traced=False, **kw):
-    return run.measure(SPEC, CELL, conf, mix, seed, 3.0, traced, PEAK,
-                       jax.devices(), kernel_mode="ref", **kw)
+    return run.measure(SPEC, _cell(conf), conf, mix, seed, 3.0, traced,
+                       PEAK, jax.devices(), kernel_mode="ref", **kw)
+
+
+def _applying(kind, conf):
+    return [m for m in SPEC[kind] if run.applies(m, _cell(conf)["name"])]
 
 
 def test_cell_loop_result_line(tiny, tiny_mix, capsys):
@@ -27,8 +35,9 @@ def test_cell_loop_result_line(tiny, tiny_mix, capsys):
     assert out["window_compiles"] == 0
     assert out["correct"] is True, out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0
-    assert set(out["metrics"]) == {m["name"] for m in SPEC["end_to_end"]}
-    for m in SPEC["end_to_end"]:
+    e2e = _applying("end_to_end", tiny)
+    assert set(out["metrics"]) == {m["name"] for m in e2e}
+    for m in e2e:
         got = out["metrics"][m["name"]]
         assert got["unit"] == m["unit"] and got["value"] > 0
     assert set(out["device"]) >= {"platform", "kind", "count",
@@ -42,11 +51,12 @@ def test_cell_loop_result_line(tiny, tiny_mix, capsys):
 def test_traced_run_reports_per_layer_metrics(tiny, tiny_mix):
     out = _measure(tiny, tiny_mix, 12, traced=True)
     assert out["correct"] is True, out["compared"]
-    names = {m["name"] for m in SPEC["per_layer"]}
+    names = {m["name"] for m in _applying("per_layer", tiny)}
     assert set(out["metrics"]) <= names
     # host-side readers find something on any platform; the device-trace
     # ones need a device plane, which the CPU run does not write
-    assert {"engine.queue_wait_p95_ms", "step_mfu"} <= set(out["metrics"])
+    assert {"engine.queue_wait_p95_ms", "step_mfu"} & names \
+        <= set(out["metrics"])
     assert set(out["device"]) >= {"busy_s", "window_s"}
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(out)[-1] == "compared"

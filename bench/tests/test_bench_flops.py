@@ -1,7 +1,11 @@
-"""Operation and byte counts against hand counts at small shapes."""
+"""Operation and byte counts against hand counts at small shapes; a
+step's totals through the dense decoder's module."""
 from types import SimpleNamespace
 
 from bench import flops
+from bench.model import load_arch
+
+DENSE = load_arch({"reference": "dense_decoder"})
 
 CFG = SimpleNamespace(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
                       d_head=2, d_ff=16, vocab=10)
@@ -45,8 +49,8 @@ def test_step_totals():
     want = (8 * flops.matmul_flops_per_token(CFG)
             + 2 * sum(flops.attn_flops(CFG, o, n) for o, n, _ in lanes)
             + 2 * flops.head_flops(CFG))
-    assert flops.step_flops(CFG, lanes) == want
-    f, b = flops.step_attn(CFG, lanes, 4, 2)
+    assert DENSE.step_flops(CFG, lanes) == want
+    f, b = DENSE.step_attn(CFG, lanes, 4, 2)
     assert f == 2 * sum(flops.attn_flops(CFG, o, n) for o, n, _ in lanes)
     assert b == 2 * sum(flops.attn_bytes(CFG, o, n, 4, 2)
                         for o, n, _ in lanes)

@@ -7,7 +7,7 @@ import re
 import pytest
 
 from bench import run
-from bench.model import load_config
+from bench.model import CONTRACT, arch_path, load_arch, load_config
 from bench.traffic import load_mix
 
 SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
@@ -41,6 +41,10 @@ def test_cell_files_resolve(cell):
     entry = next(c for c in SPEC["configs"] if c["name"] == cell["config"])
     assert entry["file"] == f"bench/configs/{cell['config']}.json"
     assert conf["name"] == cell["config"]
+    # the architecture is found by the file its reference names
+    assert arch_path(conf).is_file()
+    arch = load_arch(conf)
+    assert all(callable(getattr(arch, name, None)) for name in CONTRACT)
     for key in entry["reduced"]:
         assert key in conf["reduced"]
     srv = conf["serving"]
